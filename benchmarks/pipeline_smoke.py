@@ -136,16 +136,20 @@ def _best(fn, repeats: int) -> float:
 def _dist_pagerank_section() -> dict:
     """Distributed PageRank stats on an 8-device host mesh. XLA locks the
     device count at first init, so this runs in a subprocess with its own
-    XLA_FLAGS (exactly how the system tests do it)."""
+    XLA_FLAGS (exactly how the system tests do it). The child is pinned to
+    the CPU backend: the parent already holds any accelerator."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = str(SRC)
     out = subprocess.run(
         [sys.executable, "-c", _DIST_PR_CODE],
         capture_output=True, text=True, env=env, timeout=560,
     )
     if out.returncode != 0:
-        return {"error": (out.stderr or out.stdout).strip()[-500:]}
+        raise RuntimeError(
+            f"distributed PageRank child failed: {(out.stderr or out.stdout).strip()[-500:]}"
+        )
     return json.loads(out.stdout.strip().splitlines()[-1])
 
 

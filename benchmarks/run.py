@@ -28,6 +28,9 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
 
     from repro.api import COMPUTE_BACKENDS, benchmark_partitioners, partitioner_names
+    from repro.launch.compile_cache import use_persistent_cache
+
+    use_persistent_cache()
 
     known = partitioner_names()
     parts = list(benchmark_partitioners()) if args.partitioners is None else args.partitioners

@@ -57,7 +57,7 @@ LOOP_PRIMS = {
     "jax.lax.associative_scan",
 }
 WRAPPERS = {"jax.jit", "jax.pmap", "jax.vmap"}
-KERNEL_WRAPPERS = {"pallas_call", "shard_map", "shard_map_compat"}
+KERNEL_WRAPPERS = {"pallas_call", "shard_map"}
 
 
 def _is_static_expr(node: ast.AST) -> bool:

@@ -449,7 +449,7 @@ class GraphPipeline:
         with mesh:
             val, msgs, steps, msgs_steps, iters_steps = jax.jit(stepper)(arrays, init)
         if codec is not None:
-            val = codec.decode(val)
+            val = codec.decode(np.asarray(val))  # gathered: the table is unsharded
         steps = int(steps)
         msgs_sw = np.asarray(msgs_steps, np.int64)[:steps]
         iters_sw = np.asarray(iters_steps, np.int64)[:steps]

@@ -15,7 +15,7 @@ Partition state, not the edge list, is what stays resident:
                              (dense bool for "xla", packed uint32 bitset
                              for "ref"/"pallas" — p×⌈V/32⌉, 32x smaller).
   state_layout="sharded"     membership rows laid out along the worker
-                             axis via shard_map (repro.compat +
+                             axis via jax.shard_map (mesh from
                              launch.mesh): each device holds p/d rows,
                              scores its rows locally, and an all_gather
                              of the per-block miss tables feeds the same
@@ -106,8 +106,6 @@ def _make_sharded_step(
     The per-block miss tables are all_gather'd so every device runs the
     IDENTICAL `_score_commit_loop` (replicated compute, sharded state) —
     assignments are bit-identical to the replicated dense path."""
-    from repro.compat import shard_map_compat
-
     p = num_parts
 
     def step(keep_local, e_count, v_count, ub, vb, valb, wub, wvb, inv_e, ce, cv, eps):
@@ -135,11 +133,12 @@ def _make_sharded_step(
 
     from jax.sharding import PartitionSpec as P
 
-    sharded = shard_map_compat(
+    sharded = jax.shard_map(
         step,
         mesh=mesh,
         in_specs=(P(axis), P(), P(), P(), P(), P(), P(), P(), P(), P(), P(), P()),
         out_specs=(P(axis), P(), P(), P()),
+        check_vma=False,
     )
     return jax.jit(sharded, donate_argnums=(0, 1, 2))
 

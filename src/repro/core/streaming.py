@@ -410,68 +410,57 @@ def _streaming_chunked(
 
     e0 = jnp.zeros((p,), dtype=jnp.float32)
     v0 = jnp.zeros((p,), dtype=jnp.float32)
-
-    if backend == "xla":
-        # Dense (p, V) bool membership table, batched gathers for the score
-        # phase. Kept as the A/B baseline for the bitset path below.
-        keep0_state = jnp.zeros((p, num_vertices), dtype=jnp.bool_)
-
-        def step(state, uv_block):
-            keep, e_count, v_count = state
-            if weighted:
-                ub, vb, valb, wub, wvb = uv_block  # [B]
-            else:
-                ub, vb, valb = uv_block
-            # Vectorized membership lookups against block-start keep: (p, B),
-            # then the shared sequential exact in-block commit
-            # (`_score_commit_loop`). Pad edges are scored (uniform work
-            # per lane) but never committed: they leave e_count/v_count
-            # untouched and route to row `p`.
-            mu0 = (~keep[:, ub]).astype(jnp.float32)
-            mv0 = (~keep[:, vb]).astype(jnp.float32)
-            e_count, v_count, parts = _score_commit_loop(
-                e_count, v_count, mu0, mv0, valb,
-                wub if weighted else None, wvb if weighted else None,
-                num_parts=p, weighted=weighted, balance=balance, window=window,
-                ce=ce, cv=cv, eps=eps, inv_e=inv_e, inv_v=inv_v, ub=ub, vb=vb,
-            )
-            # Batched keep update after the block commits; pad edges carry the
-            # out-of-bounds row `p` and are dropped by the scatter.
-            keep = keep.at[parts, ub].set(True, mode="drop")
-            keep = keep.at[parts, vb].set(True, mode="drop")
-            return (keep, e_count, v_count), parts
-
-    else:
-        # Packed uint32 bitset membership (32x smaller than the dense bool
-        # table: p=32, V=1M -> 4 MB, VMEM-resident for the Pallas kernel).
-        # The whole block — membership score, argmin, exact balance commit,
-        # bitset update — runs inside one fused ops.ebg_commit_block call
-        # (ref oracle or Pallas kernel), parameterized by the scorer's
-        # coefficient vector and weight streams; assignments stay identical
-        # to the dense path because membership is pinned to block-start
-        # state and the commit arithmetic is term-for-term the same.
-        vw = (num_vertices + 31) // 32
-        keep0_state = jnp.zeros((p, vw), dtype=jnp.uint32)
-
-        def step(state, uv_block):
-            keep_bits, e_count, v_count = state
-            if weighted:
-                ub, vb, valb, wub, wvb = uv_block  # [B]
-            else:
-                ub, vb, valb = uv_block
-                wub = wvb = None
-            keep_bits, e_count, v_count, parts = ops.ebg_commit_block(
-                keep_bits, e_count, v_count, ub, vb, valb,
-                alpha=ce, beta=cv, inv_e=inv_e, inv_v=inv_v,
-                eps=eps, balance=balance, wu=wub, wv=wvb, impl=backend,
-                window=window,
-            )
-            return (keep_bits, e_count, v_count), parts
-
     blocks = [src.reshape(-1, block), dst.reshape(-1, block), valid.reshape(-1, block)]
     if weighted:
         blocks += [wu.reshape(-1, block), wv.reshape(-1, block)]
-    (keep, e_count, v_count), part = jax.lax.scan(step, (keep0_state, e0, v0), tuple(blocks))
+
+    if backend != "xla":
+        # Packed uint32 bitset membership (32x smaller than the dense bool
+        # table: p=32, V=1M -> 4 MB, VMEM-resident for the Pallas kernel).
+        # The whole block sequence — membership score, argmin, exact
+        # balance commit, bitset update — runs inside one fused
+        # ops.ebg_commit_block call (ref oracle or Pallas kernel),
+        # parameterized by the scorer's coefficient vector and weight
+        # streams; assignments stay identical to the dense path because
+        # membership is pinned to block-start state and the commit
+        # arithmetic is term-for-term the same.
+        keep, e_count, v_count, part = ops.ebg_commit_block(
+            jnp.zeros((p, (num_vertices + 31) // 32), dtype=jnp.uint32), e0, v0,
+            *blocks[:3], alpha=ce, beta=cv, inv_e=inv_e, inv_v=inv_v, eps=eps,
+            balance=balance, wu=blocks[3] if weighted else None,
+            wv=blocks[4] if weighted else None, impl=backend, window=window,
+        )
+        return part.reshape(-1), keep, e_count, v_count
+
+    # Dense (p, V) bool membership table, batched gathers for the score
+    # phase.
+    def step(state, uv_block):
+        keep, e_count, v_count = state
+        if weighted:
+            ub, vb, valb, wub, wvb = uv_block  # [B]
+        else:
+            ub, vb, valb = uv_block
+        # Vectorized membership lookups against block-start keep: (p, B),
+        # then the shared sequential exact in-block commit
+        # (`_score_commit_loop`). Pad edges are scored (uniform work
+        # per lane) but never committed: they leave e_count/v_count
+        # untouched and route to row `p`.
+        mu0 = (~keep[:, ub]).astype(jnp.float32)
+        mv0 = (~keep[:, vb]).astype(jnp.float32)
+        e_count, v_count, parts = _score_commit_loop(
+            e_count, v_count, mu0, mv0, valb,
+            wub if weighted else None, wvb if weighted else None,
+            num_parts=p, weighted=weighted, balance=balance, window=window,
+            ce=ce, cv=cv, eps=eps, inv_e=inv_e, inv_v=inv_v, ub=ub, vb=vb,
+        )
+        # Batched keep update after the block commits; pad edges carry the
+        # out-of-bounds row `p` and are dropped by the scatter.
+        keep = keep.at[parts, ub].set(True, mode="drop")
+        keep = keep.at[parts, vb].set(True, mode="drop")
+        return (keep, e_count, v_count), parts
+
+    keep0 = jnp.zeros((p, num_vertices), dtype=jnp.bool_)
+    (keep, e_count, v_count), part = jax.lax.scan(step, (keep0, e0, v0), tuple(blocks))
     return part.reshape(-1), keep, e_count, v_count
 
 

@@ -50,7 +50,6 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.api.config import check_compute_backend
-from repro.compat import shard_map_compat
 from repro.core.metrics import max_mean_ratio
 from repro.graph.build import SubgraphSet, check_addressing
 from repro.kernels import ops
@@ -1388,11 +1387,12 @@ def make_distributed_stepper(
             val_out = jnp.where(val_out >= INF_F32, INF_I32, val_out.astype(jnp.int32))
         return val_out, msgs_buf.sum(axis=0), steps, msgs_buf, iters_buf
 
-    sharded = shard_map_compat(
+    sharded = jax.shard_map(
         stepper,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=(spec2, P(axis_tuple), P(), P(None, axis_tuple), P(None, axis_tuple)),
+        check_vma=False,
     )
 
     addressing = statics.get("addressing", "two_level")

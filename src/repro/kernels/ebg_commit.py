@@ -1,39 +1,40 @@
 """Pallas TPU kernel: fused streaming-scorer block commit (score + argmin
 + commit) for the chunked vertex-cut partitioners.
 
-`ebg_membership_pallas` only covers the vectorizable score phase; the
-chunked partitioner still paid one p-wide argmin plus four scattered
-1-element updates per edge back in XLA land. This kernel fuses the whole
-per-block pipeline:
+One launch streams a whole sequence of edge blocks (grid = one step per
+block) through the sequential per-edge pipeline
 
-  1. membership of the block's 2·B endpoints against the block-start
-     packed bitset (vectorized, VPU-friendly), optionally weighted by the
-     scorer's per-edge degree term (HDRF's 2−θ streams),
-  2. the sequential per-edge argmin + exact balance-term commit,
-  3. the per-winner bitset updates,
+  1. membership of the edge's two endpoints in every subgraph's packed
+     bitset, optionally weighted by the scorer's per-edge degree term
+     (HDRF's 2−θ streams),
+  2. the argmin over subgraphs + exact balance-term commit,
+  3. the winner's bitset updates,
 
-with the (p,) e/v counters and the (p, ⌈V/32⌉) uint32 bitset resident in
-VMEM for the whole block — HBM sees one bitset read + one write per block
-instead of four scattered touches per edge. Assignments are bit-identical
-to the unfused path (`repro.kernels.ref.ebg_commit_block_ref`): membership
-is pinned to the block-start bitset, so the in-loop bit commits never feed
-back into this block's scores.
+with the (p,) e/v counters and the packed bitset resident in VMEM for the
+whole stream — HBM sees one bitset read and one write per launch.
 
-`window=True` turns the frozen commit into the speculative window commit:
-scoring is still vectorized against block-start membership, but each
-committed edge clears the now-stale membership columns of LATER in-block
-edges that share one of its endpoints, so only conflicted edges replay
-against corrected state — assignments become bit-identical to the
-one-edge-at-a-time scan driver at any block size.
+Layout: the bitset rides as [⌈Vw/128⌉, P8, 128] int32 — word w of
+subgraph i sits at [w >> 7, i, w & 127], P8 = p rounded up to 8 — so one
+dynamic leading-index load gives every subgraph's word for an endpoint in
+a single (8, 128) tile, and a lane mask picks the word. The block's edge
+ids, validity and weights are pipelined into SMEM, where the scalar core
+reads them per edge.
+
+`window=False` (frozen commit): every edge of a block is scored against
+the block-start bitset — the block's bits are committed only after its
+last edge is scored. `window=True` (speculative window commit): each
+committed edge's bits land before the next edge is scored, which is
+exactly the one-edge-at-a-time scan — assignments are bit-identical to it
+at any block size. Both are bit-identical to the oracle
+`repro.kernels.ref.ebg_commit_block_ref`. Pad edges (valid=False) are
+never committed; their assignment is the out-of-bounds row p.
 
 The scorer's coefficients ride in as a (5,) f32 vector — ce (edge-balance
 coefficient: EBV alpha / HDRF lambda), cv (vertex-balance: EBV beta),
 inv_e, inv_v (the static normalizers), eps (the range normalizer's
 epsilon) — they are traced values in the chunked driver (inv_e depends on
 the real edge count), so they cannot be static kernel parameters. The
-scorer's STRUCTURE (balance mode, degree weighting) is static: it selects
-the traced computation, keeping the stock-EBV path identical to the
-pre-generalization kernel.
+scorer's STRUCTURE (balance mode, degree weighting) is static.
 """
 from __future__ import annotations
 
@@ -42,82 +43,126 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.dispatch import default_interpret
 
+LANES = 128
+SUBLANES = 8
+# A block's SMEM/VMEM slices must sit on the 1-D HBM tiling (1024 words).
+BLOCK_ALIGN = 1024
+VMEM_FLOOR = 32 * 2**20
+
+
+def bitset_rows(num_words: int) -> int:
+    return -(-num_words // LANES)
+
+
+def vmem_bytes(num_parts: int, num_words: int, block: int) -> int:
+    """VMEM the kernel asks for: the resident bitset, the double-buffered
+    parts block, and headroom for the compiler's own scratch."""
+    p8 = -(-num_parts // SUBLANES) * SUBLANES
+    bitset = bitset_rows(num_words) * p8 * LANES * 4
+    return max(VMEM_FLOOR, bitset + 2 * 4 * block + 4 * 2**20)
+
 
 def _ebg_commit_kernel(
-    u_ref, v_ref, valid_ref, wu_ref, wv_ref, coef_ref, keep_in_ref, e_in_ref, v_in_ref,
-    keep_ref, e_ref, vc_ref, parts_ref, *, num_parts: int, balance: str, weighted: bool,
-    window: bool = False,
+    coef_ref, u_ref, v_ref, ok_ref, wu_ref, wv_ref, e_in, v_in, keep_hbm,
+    keep_out_hbm, e_out, v_out, parts_ref,
+    keep, ec, vc, sem,
+    *, num_parts: int, block: int, balance: str, weighted: bool, window: bool,
 ):
-    u = u_ref[...]
-    v = v_ref[...]
-    valid = valid_ref[...]
+    step = pl.program_id(0)
+    p8 = keep.shape[1]
+    sub = jax.lax.broadcasted_iota(jnp.int32, (p8, LANES), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (p8, LANES), 1)
+    lane1 = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+    real = sub < num_parts
+
+    @pl.when(step == 0)
+    def _load_state():
+        cp = pltpu.make_async_copy(keep_hbm, keep, sem.at[0])
+        cp.start()
+        cp.wait()
+        ec[...] = e_in[...]
+        vc[...] = v_in[...]
+
     ce, cv = coef_ref[0], coef_ref[1]
     inv_e, inv_v, eps = coef_ref[2], coef_ref[3], coef_ref[4]
-    keep = keep_in_ref[...]  # [p, Vw] block-start bitset, pinned for scoring
+    parts_ref[...] = jnp.full(parts_ref.shape, num_parts, jnp.int32)
 
-    def miss(ids):
-        words = keep[:, ids >> 5]  # [p, B] gather along the packed axis
-        bits = (words >> (ids & 31).astype(jnp.uint32)) & jnp.uint32(1)
-        return (jnp.uint32(1) - bits).astype(jnp.float32)
+    def miss(x):
+        """(P8, 128) f32, every lane: 1 where vertex x is absent from part i."""
+        t = keep[x >> 12]
+        bit = (t >> (x & 31)) & 1
+        present = jnp.max(jnp.where(lane == ((x >> 5) & 127), bit, 0), axis=1, keepdims=True)
+        return jnp.broadcast_to((1 - present).astype(jnp.float32), (p8, LANES))
 
-    mu0 = miss(u)
-    mv0 = miss(v)
-    keep_ref[...] = keep  # commit loop mutates the output copy in place
+    def set_bit(x, win):
+        """keep[i] |= {x} for the winner rows `win`."""
+        r = x >> 12
+        t = keep[r]
+        keep[r] = jnp.where(win & (lane == ((x >> 5) & 127)), t | (1 << (x & 31)), t)
 
-    def body(j, carry):
-        e_c, v_c, mu, mv = carry
+    def score_edge(j):
+        """Score edge j against the current bitset, commit the counters and
+        record the winner; returns the winner mask."""
+        uj, vj = u_ref[j], v_ref[j]
+        mu, mv = miss(uj), miss(vj)
+        e_c, v_c = ec[...], vc[...]
         if balance == "static":
             norm = inv_e
         else:
-            norm = 1.0 / (eps + (jnp.max(e_c) - jnp.min(e_c)))
-        if weighted:
-            gain = wu_ref[j] * mu[:, j] + wv_ref[j] * mv[:, j]
-        else:
-            gain = mu[:, j] + mv[:, j]
+            spread = (jnp.max(jnp.where(real, e_c, float("-inf")), axis=0, keepdims=True)
+                      - jnp.min(jnp.where(real, e_c, float("inf")), axis=0, keepdims=True))
+            norm = 1.0 / (eps + spread)
+        gain = wu_ref[j] * mu + wv_ref[j] * mv if weighted else mu + mv
         score = gain + ce * e_c * norm + cv * v_c * inv_v
-        i = jnp.argmin(score).astype(jnp.int32)  # ties -> lowest subgraph id
-        live = valid[j].astype(jnp.float32)
-        e_c = e_c.at[i].add(live)
-        v_c = v_c.at[i].add(live * (mu[i, j] + mv[i, j]))
-        pl.store(
-            parts_ref,
-            (pl.dslice(j, 1),),
-            jnp.where(valid[j] != 0, i, num_parts).reshape(1),
-        )
+        score = jnp.where(real, score, float("inf"))
+        best = jnp.min(score, axis=0, keepdims=True)
+        i = jnp.min(jnp.where(score == best, sub, p8), axis=0, keepdims=True)  # ties -> lowest
+        win = sub == i
+        ec[...] = e_c + jnp.where(win, 1.0, 0.0)
+        vc[...] = v_c + jnp.where(win, mu + mv, 0.0)
+        rows = pl.ds(j >> 7, 1)
+        prow = parts_ref[rows, :]
+        parts_ref[rows, :] = jnp.where(lane1 == (j & 127), i, prow)
+        return win
 
-        @pl.when(valid[j] != 0)
-        def _commit_bits():
-            wu = u[j] >> 5
-            bu = jnp.uint32(1) << (u[j] & 31).astype(jnp.uint32)
-            cur_u = pl.load(keep_ref, (pl.dslice(i, 1), pl.dslice(wu, 1)))
-            pl.store(keep_ref, (pl.dslice(i, 1), pl.dslice(wu, 1)), cur_u | bu)
-            # v's word is read AFTER u's store: u and v may share a word.
-            wv = v[j] >> 5
-            bv = jnp.uint32(1) << (v[j] & 31).astype(jnp.uint32)
-            cur_v = pl.load(keep_ref, (pl.dslice(i, 1), pl.dslice(wv, 1)))
-            pl.store(keep_ref, (pl.dslice(i, 1), pl.dslice(wv, 1)), cur_v | bv)
+    def commit_loop(j, c):
+        @pl.when(ok_ref[j] != 0)
+        def _commit():
+            win = score_edge(j)
+            if window:  # live state: the next edge sees these bits
+                set_bit(u_ref[j], win)
+                set_bit(v_ref[j], win)  # after u's store: u and v may share a word
 
-        if window:
-            # Speculative window commit: the block was scored from frozen
-            # state; replay this commit's membership consequences onto the
-            # remaining columns (clear the winner's miss rows wherever a
-            # later edge touches the committed endpoints) so conflicted
-            # edges score against live state — bit-identical to the scan.
-            hit_u = (u == u[j]) | (u == v[j])
-            hit_v = (v == u[j]) | (v == v[j])
-            gate = valid[j] != 0
-            mu = mu.at[i].set(jnp.where(hit_u & gate, 0.0, mu[i]))
-            mv = mv.at[i].set(jnp.where(hit_v & gate, 0.0, mv[i]))
-        return e_c, v_c, mu, mv
+        return c
 
-    e_c, v_c, _, _ = jax.lax.fori_loop(
-        0, u.shape[0], body, (e_in_ref[...], v_in_ref[...], mu0, mv0)
-    )
-    e_ref[...] = e_c
-    vc_ref[...] = v_c
+    jax.lax.fori_loop(0, block, commit_loop, 0)
+
+    if not window:  # frozen: the block's bits land after its last score
+        def bits_loop(j, c):
+            @pl.when(ok_ref[j] != 0)
+            def _bits():
+                prow = parts_ref[pl.ds(j >> 7, 1), :]
+                i = jnp.max(jnp.where(lane1 == (j & 127), prow, -1), axis=1, keepdims=True)
+                win = sub == i
+                set_bit(u_ref[j], win)
+                set_bit(v_ref[j], win)
+
+            return c
+
+        jax.lax.fori_loop(0, block, bits_loop, 0)
+
+    e_out[...] = ec[...]
+    v_out[...] = vc[...]
+
+    @pl.when(step == pl.num_programs(0) - 1)
+    def _store_state():
+        cp = pltpu.make_async_copy(keep, keep_out_hbm, sem.at[0])
+        cp.start()
+        cp.wait()
 
 
 @functools.partial(jax.jit, static_argnames=("balance", "weighted", "window", "interpret"))
@@ -125,11 +170,11 @@ def ebg_commit_block_pallas(
     keep_bits: jax.Array,  # [p, Vw] uint32
     e_count: jax.Array,  # [p] f32
     v_count: jax.Array,  # [p] f32
-    u: jax.Array,  # [B] int32
-    v: jax.Array,  # [B] int32
-    valid: jax.Array,  # [B] bool (pad edges False)
-    wu: jax.Array,  # [B] f32 membership weights (ignored unless weighted)
-    wv: jax.Array,  # [B] f32
+    u: jax.Array,  # [B] int32, or [nblk, B]: a sequence of blocks
+    v: jax.Array,  # like u
+    valid: jax.Array,  # like u, bool (pad edges False)
+    wu: jax.Array,  # like u, f32 membership weights (ignored unless weighted)
+    wv: jax.Array,  # like u
     coef: jax.Array,  # [5] f32: ce, cv, inv_e, inv_v, eps
     *,
     balance: str = "static",
@@ -137,20 +182,64 @@ def ebg_commit_block_pallas(
     window: bool = False,
     interpret: bool | None = None,
 ):
+    """Commit one block ([B] streams) or a block sequence ([nblk, B]) in one
+    launch. Returns (keep_bits, e_count, v_count, parts) with parts shaped
+    like `u`."""
     interpret = default_interpret(interpret)
     p, vw = keep_bits.shape
-    B = u.shape[0]
+    blocks = u.reshape((-1, u.shape[-1]))
+    nblk, B = blocks.shape
+    bp = -(-B // BLOCK_ALIGN) * BLOCK_ALIGN
+    p8 = -(-p // SUBLANES) * SUBLANES
+    rows = bitset_rows(vw)
+
+    def stream(x, dtype):
+        x = x.reshape(nblk, B).astype(dtype)
+        return jnp.pad(x, ((0, 0), (0, bp - B))).reshape(-1)
+
+    kb = jax.lax.bitcast_convert_type(keep_bits, jnp.int32)
+    kb = jnp.pad(kb, ((0, p8 - p), (0, rows * LANES - vw)))
+    kb = kb.reshape(p8, rows, LANES).transpose(1, 0, 2)
+
+    def counters(x):
+        return jnp.broadcast_to(jnp.pad(x.astype(jnp.float32), (0, p8 - p))[:, None], (p8, LANES))
+
+    smem_block = pl.BlockSpec((bp,), lambda b: (b,), memory_space=pltpu.SMEM)
+    whole = pl.BlockSpec((p8, LANES), lambda b: (0, 0))
+    any_space = pl.BlockSpec(memory_space=pl.ANY)
     keep_out, e_out, v_out, parts = pl.pallas_call(
         functools.partial(
-            _ebg_commit_kernel, num_parts=p, balance=balance, weighted=weighted,
+            _ebg_commit_kernel, num_parts=p, block=B, balance=balance, weighted=weighted,
             window=window,
         ),
-        out_shape=(
-            jax.ShapeDtypeStruct((p, vw), jnp.uint32),
-            jax.ShapeDtypeStruct((p,), jnp.float32),
-            jax.ShapeDtypeStruct((p,), jnp.float32),
-            jax.ShapeDtypeStruct((B,), jnp.int32),
+        grid=(nblk,),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] + [smem_block] * 5
+        + [whole, whole, any_space],
+        out_specs=[any_space, whole, whole,
+                   pl.BlockSpec((None, bp // LANES, LANES), lambda b: (b, 0, 0))],
+        out_shape=[
+            jax.ShapeDtypeStruct(kb.shape, jnp.int32),
+            jax.ShapeDtypeStruct((p8, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((p8, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((nblk, bp // LANES, LANES), jnp.int32),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM(kb.shape, jnp.int32),  # resident bitset
+            pltpu.VMEM((p8, LANES), jnp.float32),  # e_count
+            pltpu.VMEM((p8, LANES), jnp.float32),  # v_count
+            pltpu.SemaphoreType.DMA((1,)),
+        ],
+        input_output_aliases={8: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=vmem_bytes(p, vw, bp),
         ),
         interpret=interpret,
-    )(u, v, valid.astype(jnp.int32), wu, wv, coef, keep_bits, e_count, v_count)
-    return keep_out, e_out, v_out, parts
+    )(
+        coef.astype(jnp.float32), stream(u, jnp.int32), stream(v, jnp.int32),
+        stream(valid, jnp.int32), stream(wu, jnp.float32), stream(wv, jnp.float32),
+        counters(e_count), counters(v_count), kb,
+    )
+    keep_out = keep_out.transpose(1, 0, 2).reshape(p8, rows * LANES)[:p, :vw]
+    parts = parts.reshape(nblk, bp)[:, :B].reshape(u.shape)
+    return (jax.lax.bitcast_convert_type(keep_out, jnp.uint32), e_out[:p, 0], v_out[:p, 0],
+            parts)

@@ -159,16 +159,8 @@ def bsp_superstep(
             lsrc, ldst, weight, val, num_out,
             combine=combine, inner_cap=inner_cap, out_degree=out_degree,
         )
-    # Batched twin of _pad_to_block: pad every worker's stream to a
-    # multiple of block_e with identity-weight no-op edges at the dump slot.
-    p, E = lsrc.shape
-    block_e = max(min(block_e, E), 1)
-    pad = (-E) % block_e
-    if pad:
-        identity = 0.0 if combine == "sum" else float(ref.INF)
-        lsrc = jnp.concatenate([lsrc, jnp.zeros((p, pad), lsrc.dtype)], axis=1)
-        ldst = jnp.concatenate([ldst, jnp.full((p, pad), num_out - 1, ldst.dtype)], axis=1)
-        weight = jnp.concatenate([weight, jnp.full((p, pad), identity, weight.dtype)], axis=1)
+    # The kernel owns the block padding: its compiled block size is
+    # DMA-aligned, so only it knows the granularity.
     return bsp_superstep_pallas(
         lsrc, ldst, weight, val, out_degree,
         num_out=num_out, combine=combine, inner_cap=inner_cap,
@@ -200,9 +192,10 @@ def ebg_commit_block(
     impl: str | None = None, interpret: bool | None = None,
 ):
     """Fused streaming-scorer block commit: membership score + argmin +
-    exact balance commit + bitset update for a whole edge block, with the
-    (p,) counters and the (p, ⌈V/32⌉) bitset VMEM-resident on the Pallas
-    path.
+    exact balance commit + bitset update for one edge block ([B] streams)
+    or a sequence of blocks ([nblk, B], committed in order — the Pallas
+    path runs a whole edge stream in one launch), with the (p,) counters
+    and the (p, ⌈V/32⌉) bitset VMEM-resident on the Pallas path.
 
     The scorer rides in as its coefficient vector plus structure flags:
     alpha/beta are the generic edge/vertex balance coefficients (EBV's
@@ -211,8 +204,8 @@ def ebg_commit_block(
     1/(eps + max−min)), and wu/wv optionally weight the membership term
     per edge (HDRF's 2−θ degree streams). All coefficients may be traced
     scalars (inv_e depends on the real edge count). Pad edges carry
-    valid=False: they are scored (uniform lane work) but never committed,
-    and their assignment is the out-of-bounds row p. `window=True` turns
+    valid=False: they are never committed, and their assignment is the
+    out-of-bounds row p. `window=True` turns
     the frozen-membership commit into the speculative window commit:
     scores stay vectorized against block-start state, but each commit
     replays its membership consequences onto later conflicted columns —
@@ -226,11 +219,21 @@ def ebg_commit_block(
     if (wu is None) != (wv is None):
         raise ValueError("wu and wv must be given together")
     if impl == "ref":
-        return ref.ebg_commit_block_ref(
-            keep_bits, e_count, v_count, u, v, valid,
-            alpha=alpha, beta=beta, inv_e=inv_e, inv_v=inv_v,
-            eps=eps, balance=balance, wu=wu, wv=wv, window=window,
-        )
+        def one_block(state, blk):
+            ub, vb, valb, wub, wvb = blk
+            *state, parts = ref.ebg_commit_block_ref(
+                *state, ub, vb, valb,
+                alpha=alpha, beta=beta, inv_e=inv_e, inv_v=inv_v,
+                eps=eps, balance=balance, wu=wub, wv=wvb, window=window,
+            )
+            return tuple(state), parts
+
+        state, blocks = (keep_bits, e_count, v_count), (u, v, valid, wu, wv)
+        if u.ndim == 1:
+            state, parts = one_block(state, blocks)
+        else:
+            state, parts = jax.lax.scan(one_block, state, blocks)
+        return (*state, parts)
     coef = jnp.stack([
         jnp.float32(alpha), jnp.float32(beta), jnp.float32(inv_e),
         jnp.float32(inv_v), jnp.float32(eps),

@@ -79,10 +79,9 @@ def _segment_reduce_kernel(
     nruns = rank[-1] + 1
 
     def commit(r, _):
-        d = dst_of_rank[r]
-        cur = pl.load(out_ref, (pl.dslice(d, 1),))
-        upd = jnp.minimum(cur, partial[r]) if is_min else cur + partial[r]
-        pl.store(out_ref, (pl.dslice(d, 1),), upd)
+        d = pl.ds(dst_of_rank[r], 1)
+        cur = out_ref[d]
+        out_ref[d] = jnp.minimum(cur, partial[r]) if is_min else cur + partial[r]
         return _
 
     jax.lax.fori_loop(0, nruns, commit, 0)

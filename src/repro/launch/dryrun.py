@@ -32,7 +32,6 @@ import jax
 import jax.numpy as jnp
 
 from repro import configs
-from repro.compat import cost_analysis_compat
 from repro.launch.mesh import dp_axes, make_production_mesh
 from repro.launch.roofline import model_flops, parse_collectives, roofline_terms
 from repro.launch.shapes import cache_specs_shapes, input_specs
@@ -100,7 +99,7 @@ def _lower_one(cfg, shape: str, mesh, overrides: dict, *, unroll_scan: bool = Fa
 
 
 def _cost_triple(compiled):
-    cost = cost_analysis_compat(compiled)
+    cost = compiled.cost_analysis()
     coll = parse_collectives(compiled.as_text())
     return (
         float(cost.get("flops", 0.0)),
@@ -178,7 +177,7 @@ def lower_cell(arch: str, shape: str, *, multi_pod: bool, plan: str = "baseline"
         ),
         **terms,
     )
-    return rec, mem, cost_analysis_compat(compiled), None
+    return rec, mem, compiled.cost_analysis(), None
 
 
 def main():

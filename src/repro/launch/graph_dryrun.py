@@ -13,7 +13,6 @@ entry a concretely partitioned pipeline uses for distributed execution.
 from __future__ import annotations
 
 from repro.api import GraphPipeline, SubgraphSpec
-from repro.compat import cost_analysis_compat
 from repro.launch.mesh import make_production_mesh
 from repro.launch.roofline import parse_collectives, roofline_terms
 
@@ -61,7 +60,7 @@ def run_graph_dryrun(
         compute_backend=compute_backend,
     )
     mem = low.compiled.memory_analysis()
-    cost = cost_analysis_compat(low.compiled)
+    cost = low.compiled.cost_analysis()
     coll = parse_collectives(low.compiled.as_text())
     flops = float(cost.get("flops", 0.0))
     hbm = float(cost.get("bytes accessed", 0.0))

@@ -22,6 +22,7 @@ import json
 
 from repro.api import GraphPipeline
 from repro.graph.generate import rmat
+from repro.launch.compile_cache import use_persistent_cache
 from repro.resilience import FaultPlan, RetryPolicy
 from repro.serve.trace import synthetic_trace
 
@@ -122,6 +123,7 @@ def main(argv=None):
     ap.add_argument("--max-queue", type=int, default=None,
                     help="admission queue bound (overflow load-sheds)")
     args = ap.parse_args(argv)
+    use_persistent_cache()
     out = run_graph_serve(
         num_vertices=args.vertices, num_edges=args.edges, parts=args.parts,
         partitioner=args.partitioner, queries=args.queries, rate_qps=args.rate,

@@ -7,14 +7,18 @@ from __future__ import annotations
 
 import jax
 
-from repro.compat import make_mesh_compat  # noqa: F401  (re-exported)
+
+
+def make_mesh(shape: tuple, axes: tuple):
+    """jax.make_mesh with Auto axis types (sharding propagated by XLA)."""
+    return jax.make_mesh(shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 v5e pod (data, model); 2 pods add a leading 'pod' axis."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def dp_axes(mesh) -> tuple[str, ...]:
@@ -26,4 +30,4 @@ def dp_axes(mesh) -> tuple[str, ...]:
 def make_host_mesh(n: int | None = None, name: str = "workers"):
     """Flat mesh over available devices (tests, examples, graph engine)."""
     n = n or len(jax.devices())
-    return make_mesh_compat((n,), (name,))
+    return make_mesh((n,), (name,))

@@ -19,7 +19,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map_compat
 from repro.models import pspec
 from repro.models.config import ModelConfig
 
@@ -135,12 +134,13 @@ def moe_ffn_ep(cfg: ModelConfig, p: dict, x: jax.Array) -> jax.Array:
     body = lambda xb, router, wg, wi, wo: _moe_body(
         cfg, xb, router, wg, wi, wo, tp_axis=tp
     )
-    y = shard_map_compat(
+    y = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(dp, None), P(None, None), P(tp, None, None),
                   P(tp, None, None), P(tp, None, None)),
         out_specs=P(dp, None),
+        check_vma=False,
     )(xf, p["router"], p["w_gate"], p["w_in"], p["w_out"])
     return y.reshape(B, S, d)
 

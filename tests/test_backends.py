@@ -220,14 +220,14 @@ def test_distributed_stepper_rejects_huge_vertex_ids(small_powerlaw):
         make_distributed_stepper,
         subgraphs_to_arrays,
     )
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_mesh
 
     res = PARTITIONERS["ebg"](small_powerlaw, 1)
     sub = build_subgraphs(small_powerlaw, res, symmetrize=True)
     big = dataclasses.replace(
         sub, gid=jnp.where(sub.vmask, sub.gid + (1 << 24), sub.gid), addressing="flat"
     )
-    mesh = make_mesh_compat((1,), ("workers",))
+    mesh = make_mesh((1,), ("workers",))
     arrays, statics = subgraphs_to_arrays(big)
     stepper = make_distributed_stepper(
         mesh, "workers", CC, statics, num_supersteps=4, inner_cap=100,

@@ -180,11 +180,11 @@ def test_distributed_stepper_crash_hook(small_powerlaw):
     from repro.core import PARTITIONERS
     from repro.graph.build import build_subgraphs
     from repro.graph.engine import CC, init_cc, make_distributed_stepper, subgraphs_to_arrays
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_mesh
 
     res = PARTITIONERS["ebg"](small_powerlaw, 1)
     sub = build_subgraphs(small_powerlaw, res, symmetrize=True)
-    mesh = make_mesh_compat((1,), ("workers",))
+    mesh = make_mesh((1,), ("workers",))
     arrays, statics = subgraphs_to_arrays(sub)
     crashy = make_distributed_stepper(
         mesh, "workers", CC, statics, num_supersteps=10, inner_cap=100,

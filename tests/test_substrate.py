@@ -87,10 +87,10 @@ def test_elastic_reshard_devices():
     """Gather a sharded tree and re-put to a different layout (1 device CPU
     degenerates to identity but exercises the full code path)."""
     from repro.launch.elastic import reshard
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_mesh
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    mesh = make_mesh_compat((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     tree = dict(w=jnp.ones((8, 8)))
     sh = dict(w=NamedSharding(mesh, P("data", None)))
     out = reshard(tree, sh)

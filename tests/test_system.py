@@ -55,8 +55,8 @@ g = make_graph("tiny_powerlaw")
 res = ebg_partition(g, 8)
 sub = build_subgraphs(g, res, symmetrize=True)
 labels_sim, stats_sim = alg.connected_components(sub)
-from repro.launch.mesh import make_mesh_compat
-mesh = make_mesh_compat((8,), ("workers",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("workers",))
 arrays, statics = subgraphs_to_arrays(sub)
 stepper = make_distributed_stepper(mesh, "workers", CC, statics, num_supersteps=10, inner_cap=100)
 with mesh:
@@ -89,8 +89,8 @@ from repro.models.transformer import init_params
 from repro.optim.adam import AdamWConfig, init_opt_state
 
 cfg = configs.reduced_config("phi3_5_moe")
-from repro.launch.mesh import make_mesh_compat
-mesh = make_mesh_compat((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 params_shape = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16))
 p_shard = param_shardings(cfg, params_shape, mesh)
 opt = AdamWConfig()
@@ -105,8 +105,7 @@ with mesh, activation_axes(mesh, dp=("data",), tp="model"):
                       out_shardings=(p_shard, o_shard, None)).lower(params_shape, opt_shape, batch)
     compiled = lowered.compile()
 assert compiled.memory_analysis() is not None
-from repro.compat import cost_analysis_compat
-cost = cost_analysis_compat(compiled)
+cost = compiled.cost_analysis()
 assert cost.get("flops", 0) > 0
 print("OK")
 """
@@ -168,8 +167,8 @@ p = jax.tree.map(lambda x: x[0], params["groups"]["layer_0"])["moe"]
 rng = np.random.default_rng(0)
 x = jnp.array(rng.standard_normal((4, 16, cfg.d_model)), jnp.float32)
 y_ref = MOE.moe_ffn(cfg, p, x)
-from repro.launch.mesh import make_mesh_compat
-mesh = make_mesh_compat((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 with mesh, activation_axes(mesh, dp=("data",), tp="model", ep_shard_map=True):
     y_ep = jax.jit(lambda p, x: MOE.moe_ffn_ep(cfg, p, x))(p, x)
     g = jax.jit(jax.grad(lambda p, x: MOE.moe_ffn_ep(cfg, p, x).sum()))(p, x)
