@@ -1,23 +1,23 @@
 #!/usr/bin/env python3
 """Chip smoke: the graph system's main path once, end to end, on a TPU.
 
-  python chip_smoke.py [--seed 0] [--scale 22] [--engine-scale 20]   # one chip
-  python chip_smoke.py --chips 4 [--engine-scale 20]   # four chips: mode="dist" only
+  python chip_smoke.py [--seed 0]              # one chip
+  python chip_smoke.py --chips 4 [--seed 0]    # four chips: mode="dist" only
 
 One chip: a Graph500 R-MAT graph (a=0.57, b=0.19, c=0.19, edge factor 16)
-at `--scale` (2^scale vertices, 16·2^scale edges) is generated from the
-seed and partitioned with the chunked EBV partitioner on the compiled
-Pallas commit kernel. A graph at `--engine-scale` (the same graph when the
-scales match) is partitioned, built into per-worker subgraphs, run through
-cc, sssp, bfs, reach and pr on both engine backends ("xla" and the
-compiled Pallas megakernel), and served 32 BFS/SSSP point queries. Every
-phase checks its output: partition bit parity against the XLA and scan
-partitioners, engine bit parity between backends plus agreement with the
-numpy oracles, served answers equal to single-source runs.
+at scale 22 (2^22 vertices, 2^26 edges) is generated from the seed and
+partitioned with the chunked EBV partitioner on the compiled Pallas commit
+kernel. A second graph at scale 20 is partitioned, built into per-worker
+subgraphs, run through cc, sssp, bfs, reach and pr on both engine backends
+("xla" and the compiled Pallas megakernel), and served 32 BFS/SSSP point
+queries: at scale 22 those phases would not fit the run's time limit.
+Every phase checks its output: partition bit parity against the XLA and
+scan partitioners, engine bit parity between backends plus agreement with
+the numpy oracles, served answers equal to single-source runs.
 
-Four chips: cc and pr with mode="dist" (shard_map + all_to_all, one
-subgraph per chip) on both backends, against the same programs in sim
-mode on one chip.
+Four chips: cc and pr at scale 20 with mode="dist" (shard_map +
+all_to_all, one subgraph per chip) on both backends, against the same
+programs in sim mode on one chip.
 
 The walls printed along the way are smoke timings, not benchmark numbers.
 The last line is the device report; it is printed only when every phase
@@ -35,6 +35,8 @@ import time
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), "src"))
 
 PARTS = 8
+SCALE = 22  # partition phase
+ENGINE_SCALE = 20  # build, engine and serving phases; the --chips 4 run
 PARITY_SCALE = 16
 PROGRAMS = ("cc", "sssp", "bfs", "reach", "pr")
 PR_ITERS = 20
@@ -128,6 +130,28 @@ def oracle(g, name: str, source):
     return alg.pagerank_reference(g, num_iters=PR_ITERS)
 
 
+@contextlib.contextmanager
+def recording_fused_bsp(calls: list):
+    """Record the (abstract arguments, static options) of every fused BSP
+    program `GraphPipeline.run` launches, so the exact program can be
+    lowered again and inspected."""
+    import jax
+
+    from repro.graph import engine
+
+    fused = engine._fused_bsp
+
+    def record(sub, val, **kw):
+        calls.append((jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), (sub, val)), kw))
+        return fused(sub, val, **kw)
+
+    engine._fused_bsp = record
+    try:
+        yield fused
+    finally:
+        engine._fused_bsp = fused
+
+
 def engine_phase(pipe):
     import numpy as np
 
@@ -139,9 +163,15 @@ def engine_phase(pipe):
     for name in PROGRAMS:
         runs = {}
         for backend in ("xla", "pallas"):
-            with phase(f"engine {name}/{backend}"):
+            calls = []
+            with phase(f"engine {name}/{backend}"), recording_fused_bsp(calls) as fused:
                 runs[backend] = pipe.run(name, compute_backend=backend, num_iters=PR_ITERS) \
                     if name == "pr" else pipe.run(name, compute_backend=backend)
+        # The pallas run's program carries the compiled Mosaic kernel.
+        check(len(calls) == 1, f"{name}/pallas: expected one fused program, saw {len(calls)}")
+        (abstract, kw), = calls
+        check(kw["backend"] == "pallas" and "tpu_custom_call" in fused.lower(*abstract, **kw).as_text(),
+              f"{name}: the pallas run's program has no tpu_custom_call")
         x, k = runs["xla"], runs["pallas"]
         check(np.array_equal(x.values, k.values), f"{name}: pallas values != xla values")
         check(stats_equal(x.stats, k.stats), f"{name}: pallas BSPStats != xla BSPStats")
@@ -159,16 +189,8 @@ def engine_phase(pipe):
             check(np.array_equal(got, want), f"{name}: engine differs from the oracle")
             detail = "exact"
         print(f"engine {name}: pallas == xla bitwise (values + BSPStats, "
-              f"{k.stats.supersteps} supersteps), oracle agreement {detail}", flush=True)
-
-    # The pallas run's program carries the compiled Mosaic kernel.
-    sub = pipe.subgraphs_for(symmetrize=True)
-    lowered = engine._fused_bsp.lower(
-        sub, engine.CC.init(sub), prog=engine.CC, max_supersteps=200, inner_cap=10_000,
-        exchange_period=1, tol=0.0, num_vertices=g.num_vertices, backend="pallas",
-    )
-    check("tpu_custom_call" in lowered.as_text(), "pallas engine program has no tpu_custom_call")
-    print("engine: the pallas program lowers to tpu_custom_call", flush=True)
+              f"{k.stats.supersteps} supersteps), oracle agreement {detail}, "
+              "the pallas run's program holds a tpu_custom_call", flush=True)
 
 
 def serve_phase(pipe, seed: int):
@@ -206,39 +228,36 @@ def load(scale: int, seed: int):
     return g
 
 
-def one_chip(args) -> None:
+def one_chip(seed: int) -> None:
     from repro.api import GraphPipeline
 
-    pipe = partition_phase(load(args.scale, args.seed), args.seed)
-    if args.engine_scale != args.scale:
-        more = 2 ** (args.scale - args.engine_scale)
-        print(f"cut: engine and serving run at scale {args.engine_scale}, not {args.scale}. "
-              "They cost ~19 ns per edge visit on a TPU v5e (XLA and Pallas alike); "
-              f"scale {args.scale} has {more}x the edges, so ~{more}x their walls, "
-              "past the run's 1200 s limit", flush=True)
-        g = load(args.engine_scale, args.seed)
-        with phase("partition for the engine (pallas)"):
-            pipe = GraphPipeline(g).partition(
-                "ebg_chunked", parts=PARTS, commit="window", compute_backend="pallas", block=1024
-            )
-            pipe.result
+    partition_phase(load(SCALE, seed), seed)
+    print(f"cut: build, engine and serving run at scale {ENGINE_SCALE}, not {SCALE}: on one "
+          f"TPU v5e they take ~620 s at scale {ENGINE_SCALE}, and scale {SCALE} has "
+          f"{2 ** (SCALE - ENGINE_SCALE)}x the edges, past the run's 1200 s limit", flush=True)
+    g = load(ENGINE_SCALE, seed)
+    with phase("partition for the engine (pallas)"):
+        pipe = GraphPipeline(g).partition(
+            "ebg_chunked", parts=PARTS, commit="window", compute_backend="pallas", block=1024
+        )
+        pipe.result
     with phase("build (symmetrized + directed)"):
         pipe.prepare("cc")
         pipe.prepare("bfs")
     engine_phase(pipe)
-    serve_phase(pipe, args.seed)
+    serve_phase(pipe, seed)
 
 
 # ---------------------------------------------------------- four chips
 
 
-def four_chips(args) -> None:
+def four_chips(seed: int) -> None:
     import jax
     import numpy as np
 
     from repro.api import GraphPipeline
 
-    g = load(args.scale, args.seed)
+    g = load(ENGINE_SCALE, seed)
     p = len(jax.devices())
     with phase("partition + build"):
         pipe = GraphPipeline(g).partition("ebg_chunked", parts=p, commit="window",
@@ -262,16 +281,8 @@ def four_chips(args) -> None:
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__, formatter_class=argparse.RawTextHelpFormatter)
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--scale", type=int, default=None,
-                    help="R-MAT scale of the partition phase (default 22; 20 with --chips 4)")
-    ap.add_argument("--engine-scale", type=int, default=20,
-                    help="R-MAT scale of the engine and serving phases (default 20)")
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
     args = ap.parse_args(argv)
-    if args.scale is None:
-        args.scale = 22 if args.chips == 1 else args.engine_scale
-    if args.engine_scale > args.scale:
-        ap.error("--engine-scale cannot exceed --scale")
 
     import jax
 
@@ -287,9 +298,9 @@ def main(argv=None) -> int:
     check(dispatch.default_interpret(None) is False, "Pallas would run in interpret mode")
     if args.chips == 4:
         check(device["count"] == 4, f"--chips 4 needs 4 devices, found {device['count']}")
-        four_chips(args)
+        four_chips(args.seed)
     else:
-        one_chip(args)
+        one_chip(args.seed)
     print(json.dumps({"ok": True, "device": device}))
     return 0
 

@@ -13,7 +13,11 @@ Partition state, not the edge list, is what stays resident:
 
   state_layout="replicated"  one device holds the whole membership table
                              (dense bool for "xla", packed uint32 bitset
-                             for "ref"/"pallas" — p×⌈V/32⌉, 32x smaller).
+                             for "ref"/"pallas" — p×⌈V/32⌉, 32x smaller;
+                             "pallas" holds it in the commit kernel's
+                             tiled layout for the whole stream, so a
+                             per-block launch moves it HBM↔VMEM once
+                             each way and nothing converts it).
   state_layout="sharded"     membership rows laid out along the worker
                              axis via jax.shard_map (mesh from
                              launch.mesh): each device holds p/d rows,
@@ -48,6 +52,7 @@ from repro.data.edgeshards import (
     degrees_from_shards,
 )
 from repro.kernels import ops
+from repro.kernels.ebg_commit import tiles_shape
 
 STATE_LAYOUTS = ("replicated", "sharded")
 
@@ -88,13 +93,11 @@ def _oc_block_step(
         keep = keep.at[parts, ub].set(True, mode="drop")
         keep = keep.at[parts, vb].set(True, mode="drop")
         return keep, e_count, v_count, parts
-    keep, e_count, v_count, parts = ops.ebg_commit_block(
-        keep, e_count, v_count, ub, vb, valb,
-        alpha=ce, beta=cv, inv_e=inv_e, inv_v=inv_v, eps=eps, balance=balance,
-        wu=wub if weighted else None, wv=wvb if weighted else None,
-        impl=backend, window=window,
-    )
-    return keep, e_count, v_count, parts
+    kw = dict(alpha=ce, beta=cv, inv_e=inv_e, inv_v=inv_v, eps=eps, balance=balance,
+              wu=wub if weighted else None, wv=wvb if weighted else None, window=window)
+    if backend == "pallas":  # `keep` stays in the kernel's tiled layout across blocks
+        return ops.ebg_commit_tiles(keep, e_count, v_count, ub, vb, valb, num_parts=p, **kw)
+    return ops.ebg_commit_block(keep, e_count, v_count, ub, vb, valb, impl=backend, **kw)
 
 
 def _make_sharded_step(
@@ -258,6 +261,8 @@ def partition_store(
         )
         if compute_backend == "xla":
             keep = jnp.zeros((p, V), jnp.bool_)
+        elif compute_backend == "pallas":
+            keep = jnp.zeros(tiles_shape(p, (V + 31) // 32), jnp.int32)
         else:
             keep = jnp.zeros((p, (V + 31) // 32), jnp.uint32)
 

@@ -16,9 +16,12 @@ whole stream — HBM sees one bitset read and one write per launch.
 Layout: the bitset rides as [⌈Vw/128⌉, P8, 128] int32 — word w of
 subgraph i sits at [w >> 7, i, w & 127], P8 = p rounded up to 8 — so one
 dynamic leading-index load gives every subgraph's word for an endpoint in
-a single (8, 128) tile, and a lane mask picks the word. The block's edge
-ids, validity and weights are pipelined into SMEM, where the scalar core
-reads them per edge.
+a single (8, 128) tile, and a lane mask picks the word. `to_tiles` /
+`from_tiles` convert from and to the [p, Vw] uint32 bitset; a caller that
+carries the bitset across launches (the out-of-core driver, one launch per
+block) keeps it tiled and calls `ebg_commit_tiles_pallas`. The block's
+edge ids, validity and weights are pipelined into SMEM, where the scalar
+core reads them per edge.
 
 `window=False` (frozen commit): every edge of a block is scored against
 the block-start bitset — the block's bits are committed only after its
@@ -54,16 +57,32 @@ BLOCK_ALIGN = 1024
 VMEM_FLOOR = 32 * 2**20
 
 
-def bitset_rows(num_words: int) -> int:
-    return -(-num_words // LANES)
+def tiles_shape(num_parts: int, num_words: int) -> tuple[int, int, int]:
+    """Shape of the kernel's bitset layout for a [num_parts, num_words] bitset."""
+    return (-(-num_words // LANES), -(-num_parts // SUBLANES) * SUBLANES, LANES)
 
 
-def vmem_bytes(num_parts: int, num_words: int, block: int) -> int:
+def to_tiles(keep_bits: jax.Array) -> jax.Array:
+    """[p, Vw] uint32 bitset -> the kernel's [⌈Vw/128⌉, P8, 128] int32 layout."""
+    p, vw = keep_bits.shape
+    rows, p8, _ = tiles_shape(p, vw)
+    kb = jax.lax.bitcast_convert_type(keep_bits, jnp.int32)
+    kb = jnp.pad(kb, ((0, p8 - p), (0, rows * LANES - vw)))
+    return kb.reshape(p8, rows, LANES).transpose(1, 0, 2)
+
+
+def from_tiles(tiles: jax.Array, num_parts: int, num_words: int) -> jax.Array:
+    """Inverse of `to_tiles`."""
+    rows, p8, _ = tiles.shape
+    kb = tiles.transpose(1, 0, 2).reshape(p8, rows * LANES)[:num_parts, :num_words]
+    return jax.lax.bitcast_convert_type(kb, jnp.uint32)
+
+
+def vmem_bytes(tiles: tuple[int, int, int], block: int) -> int:
     """VMEM the kernel asks for: the resident bitset, the double-buffered
     parts block, and headroom for the compiler's own scratch."""
-    p8 = -(-num_parts // SUBLANES) * SUBLANES
-    bitset = bitset_rows(num_words) * p8 * LANES * 4
-    return max(VMEM_FLOOR, bitset + 2 * 4 * block + 4 * 2**20)
+    rows, p8, lanes = tiles
+    return max(VMEM_FLOOR, rows * p8 * lanes * 4 + 2 * 4 * block + 4 * 2**20)
 
 
 def _ebg_commit_kernel(
@@ -165,9 +184,10 @@ def _ebg_commit_kernel(
         cp.wait()
 
 
-@functools.partial(jax.jit, static_argnames=("balance", "weighted", "window", "interpret"))
-def ebg_commit_block_pallas(
-    keep_bits: jax.Array,  # [p, Vw] uint32
+@functools.partial(
+    jax.jit, static_argnames=("num_parts", "balance", "weighted", "window", "interpret"))
+def ebg_commit_tiles_pallas(
+    tiles: jax.Array,  # [⌈Vw/128⌉, P8, 128] int32: the bitset in the `to_tiles` layout
     e_count: jax.Array,  # [p] f32
     v_count: jax.Array,  # [p] f32
     u: jax.Array,  # [B] int32, or [nblk, B]: a sequence of blocks
@@ -177,29 +197,26 @@ def ebg_commit_block_pallas(
     wv: jax.Array,  # like u
     coef: jax.Array,  # [5] f32: ce, cv, inv_e, inv_v, eps
     *,
+    num_parts: int,
     balance: str = "static",
     weighted: bool = False,
     window: bool = False,
     interpret: bool | None = None,
 ):
     """Commit one block ([B] streams) or a block sequence ([nblk, B]) in one
-    launch. Returns (keep_bits, e_count, v_count, parts) with parts shaped
-    like `u`."""
+    launch, on a bitset already in the kernel's layout — callers that carry
+    the bitset across launches keep it tiled. Returns (tiles, e_count,
+    v_count, parts) with parts shaped like `u`."""
     interpret = default_interpret(interpret)
-    p, vw = keep_bits.shape
+    p = num_parts
+    p8 = tiles.shape[1]
     blocks = u.reshape((-1, u.shape[-1]))
     nblk, B = blocks.shape
     bp = -(-B // BLOCK_ALIGN) * BLOCK_ALIGN
-    p8 = -(-p // SUBLANES) * SUBLANES
-    rows = bitset_rows(vw)
 
     def stream(x, dtype):
         x = x.reshape(nblk, B).astype(dtype)
         return jnp.pad(x, ((0, 0), (0, bp - B))).reshape(-1)
-
-    kb = jax.lax.bitcast_convert_type(keep_bits, jnp.int32)
-    kb = jnp.pad(kb, ((0, p8 - p), (0, rows * LANES - vw)))
-    kb = kb.reshape(p8, rows, LANES).transpose(1, 0, 2)
 
     def counters(x):
         return jnp.broadcast_to(jnp.pad(x.astype(jnp.float32), (0, p8 - p))[:, None], (p8, LANES))
@@ -218,28 +235,43 @@ def ebg_commit_block_pallas(
         out_specs=[any_space, whole, whole,
                    pl.BlockSpec((None, bp // LANES, LANES), lambda b: (b, 0, 0))],
         out_shape=[
-            jax.ShapeDtypeStruct(kb.shape, jnp.int32),
+            jax.ShapeDtypeStruct(tiles.shape, jnp.int32),
             jax.ShapeDtypeStruct((p8, LANES), jnp.float32),
             jax.ShapeDtypeStruct((p8, LANES), jnp.float32),
             jax.ShapeDtypeStruct((nblk, bp // LANES, LANES), jnp.int32),
         ],
         scratch_shapes=[
-            pltpu.VMEM(kb.shape, jnp.int32),  # resident bitset
+            pltpu.VMEM(tiles.shape, jnp.int32),  # resident bitset
             pltpu.VMEM((p8, LANES), jnp.float32),  # e_count
             pltpu.VMEM((p8, LANES), jnp.float32),  # v_count
             pltpu.SemaphoreType.DMA((1,)),
         ],
         input_output_aliases={8: 0},
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",), vmem_limit_bytes=vmem_bytes(p, vw, bp),
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=vmem_bytes(tiles.shape, bp),
         ),
         interpret=interpret,
     )(
         coef.astype(jnp.float32), stream(u, jnp.int32), stream(v, jnp.int32),
         stream(valid, jnp.int32), stream(wu, jnp.float32), stream(wv, jnp.float32),
-        counters(e_count), counters(v_count), kb,
+        counters(e_count), counters(v_count), tiles,
     )
-    keep_out = keep_out.transpose(1, 0, 2).reshape(p8, rows * LANES)[:p, :vw]
     parts = parts.reshape(nblk, bp)[:, :B].reshape(u.shape)
-    return (jax.lax.bitcast_convert_type(keep_out, jnp.uint32), e_out[:p, 0], v_out[:p, 0],
-            parts)
+    return keep_out, e_out[:p, 0], v_out[:p, 0], parts
+
+
+@functools.partial(jax.jit, static_argnames=("balance", "weighted", "window", "interpret"))
+def ebg_commit_block_pallas(
+    keep_bits, e_count, v_count, u, v, valid, wu, wv, coef, *,
+    balance: str = "static", weighted: bool = False, window: bool = False,
+    interpret: bool | None = None,
+):
+    """`ebg_commit_tiles_pallas` on a [p, Vw] uint32 bitset: converts to the
+    kernel's layout and back around one launch. Returns (keep_bits,
+    e_count, v_count, parts)."""
+    p, vw = keep_bits.shape
+    tiles, e_count, v_count, parts = ebg_commit_tiles_pallas(
+        to_tiles(keep_bits), e_count, v_count, u, v, valid, wu, wv, coef,
+        num_parts=p, balance=balance, weighted=weighted, window=window, interpret=interpret,
+    )
+    return from_tiles(tiles, p, vw), e_count, v_count, parts

@@ -22,7 +22,7 @@ from repro.kernels import ref
 from repro.kernels.bsp_superstep import bsp_superstep_pallas
 from repro.kernels.decode_attn import decode_attention_pallas
 from repro.kernels.dispatch import default_interpret, platform_is_tpu
-from repro.kernels.ebg_commit import ebg_commit_block_pallas
+from repro.kernels.ebg_commit import ebg_commit_block_pallas, ebg_commit_tiles_pallas
 from repro.kernels.ebg_score import ebg_membership_pallas
 from repro.kernels.segment_reduce import segment_reduce_pallas
 
@@ -234,15 +234,39 @@ def ebg_commit_block(
         else:
             state, parts = jax.lax.scan(one_block, state, blocks)
         return (*state, parts)
+    weighted = wu is not None
+    coef, wu, wv = _ebg_commit_args(alpha, beta, inv_e, inv_v, eps, u, wu, wv)
+    return ebg_commit_block_pallas(
+        keep_bits, e_count, v_count, u, v, valid, wu, wv, coef,
+        balance=balance, weighted=weighted, window=window, interpret=interpret,
+    )
+
+
+def _ebg_commit_args(alpha, beta, inv_e, inv_v, eps, u, wu, wv):
+    """The commit kernel's coefficient vector and weight streams (zeros
+    when unweighted)."""
     coef = jnp.stack([
         jnp.float32(alpha), jnp.float32(beta), jnp.float32(inv_e),
         jnp.float32(inv_v), jnp.float32(eps),
     ])
+    if wu is None:
+        return coef, jnp.zeros(u.shape, jnp.float32), jnp.zeros(u.shape, jnp.float32)
+    return coef, wu, wv
+
+
+def ebg_commit_tiles(
+    tiles, e_count, v_count, u, v, valid, *, num_parts: int,
+    alpha, beta, inv_e, inv_v, eps=1.0, balance: str = "static",
+    wu=None, wv=None, window: bool = False, interpret: bool | None = None,
+):
+    """`ebg_commit_block` on the Pallas kernel, with the bitset in the
+    kernel's own layout (`kernels.ebg_commit.to_tiles`) so a caller that
+    launches once per block converts it once, not per launch. Returns
+    (tiles, e_count, v_count, parts)."""
     weighted = wu is not None
-    if not weighted:
-        wu = wv = jnp.zeros(u.shape, jnp.float32)
-    return ebg_commit_block_pallas(
-        keep_bits, e_count, v_count, u, v, valid, wu, wv, coef,
+    coef, wu, wv = _ebg_commit_args(alpha, beta, inv_e, inv_v, eps, u, wu, wv)
+    return ebg_commit_tiles_pallas(
+        tiles, e_count, v_count, u, v, valid, wu, wv, coef, num_parts=num_parts,
         balance=balance, weighted=weighted, window=window, interpret=interpret,
     )
 

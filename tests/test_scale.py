@@ -102,7 +102,8 @@ def test_rmat_to_store_deterministic_and_valid(tmp_path):
 @pytest.mark.parametrize("commit", ("frozen", "window"))
 @pytest.mark.parametrize(
     "scorer,backend",
-    [("ebv", "xla"), ("ebv", "ref"), ("hdrf", "xla"), ("hdrf", "ref"), ("greedy", "xla")],
+    [("ebv", "xla"), ("ebv", "ref"), ("ebv", "pallas"), ("hdrf", "xla"), ("hdrf", "ref"),
+     ("hdrf", "pallas"), ("greedy", "xla")],
 )
 def test_partition_store_matches_in_memory(graph, store, tmp_path, scorer, backend, commit):
     r_mem = streaming_chunked_partition(

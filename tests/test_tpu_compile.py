@@ -16,8 +16,9 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels import ops
 from repro.kernels.bsp_superstep import bsp_superstep_pallas
-from repro.kernels.ebg_commit import ebg_commit_block_pallas
+from repro.kernels.ebg_commit import ebg_commit_block_pallas, tiles_shape
 
 PARTS = 8
 SMOKE_NUM_OUT = 655_361  # per-worker value width at scale 22 (max_v + dump slot)
@@ -118,6 +119,22 @@ def test_bsp_superstep_vmapped_compiles(one_chip):
 def test_ebg_commit_compiles_at_smoke_width(one_chip, window, weighted):
     compiled = _compile_ebg(one_chip, SMOKE_VERTICES, window, weighted)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_ebg_commit_tiles_one_block_compiles(one_chip):
+    """The out-of-core driver's launch: one [B] block on a bitset kept in
+    the kernel's tiled layout, with no layout conversion around it."""
+    vw = (SMOKE_VERTICES + 31) // 32
+    stream = [_sds(one_chip, (4096,), dt) for dt in (jnp.int32, jnp.int32, jnp.bool_)]
+    fn = jax.jit(lambda t, e, v, *s: ops.ebg_commit_tiles(
+        t, e, v, *s, num_parts=PARTS, alpha=1.0, beta=1.0, inv_e=1e-6, inv_v=1e-6,
+        window=True, interpret=False))
+    compiled = fn.lower(
+        _sds(one_chip, tiles_shape(PARTS, vw), jnp.int32), _sds(one_chip, (PARTS,), jnp.float32),
+        _sds(one_chip, (PARTS,), jnp.float32), *stream,
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "transpose" not in text
 
 
 def test_vmem_limits_match_docs(one_chip):
