@@ -4,6 +4,9 @@ ONE reference loop covers every registered scorer (EBV, HDRF, Greedy, and
 custom instances): float32 state mutated in the same op order as the JAX
 drivers in `repro.core.streaming`, so both implementations resolve
 near-ties identically and the parity tests can assert exact equality.
+XLA's CPU backend contracts each multiply-add of the score into one fused
+multiply-add (a single rounding); the oracle rounds those the same way,
+or a tie such as 1.25 + 5·(1/6) vs 1.75 + 2·(1/6) splits by one ulp.
 """
 from __future__ import annotations
 
@@ -14,6 +17,12 @@ import numpy as np
 from repro.core.order import degree_sum_order
 from repro.core.streaming import EdgeScorer, edge_weights_np, get_scorer
 from repro.core.types import Graph, PartitionResult
+
+
+def _fma32(a, b, c):
+    """float32 a·b + c rounded once: the f32 product is exact in float64,
+    and the float64 sum is exact at the score magnitudes the loop meets."""
+    return (np.asarray(a, np.float64) * np.float64(b) + c).astype(np.float32)
 
 
 def streaming_partition_np(
@@ -57,7 +66,7 @@ def streaming_partition_np(
         mv = (~keep[:, v]).astype(np.float32)
         base = w[0][m] * mu + w[1][m] * mv if w is not None else mu + mv
         norm = inv_e if static else np.float32(1.0) / (eps + (e_count.max() - e_count.min()))
-        score = base + ce * e_count * norm + cv * v_count * inv_v
+        score = _fma32(cv * v_count, inv_v, _fma32(ce * e_count, norm, base))
         i = int(np.argmin(score))
         part[m] = i
         e_count[i] += 1
