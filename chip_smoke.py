@@ -79,7 +79,7 @@ def stats_equal(a, b) -> bool:
 # ------------------------------------------------------------- one chip
 
 
-def partition_phase(g, seed: int):
+def partition_phase(g, seed: int) -> None:
     import numpy as np
 
     from repro.api import GraphPipeline
@@ -113,7 +113,6 @@ def partition_phase(g, seed: int):
                   f"ebg_chunked[{name}] != ebg scan at scale {PARITY_SCALE}")
     print(f"partition parity: ebg_chunked pallas == xla == ebg scan on "
           f"{small.num_edges} edges (scale {PARITY_SCALE})", flush=True)
-    return pipe
 
 
 def oracle(g, name: str, source):
