@@ -15,7 +15,6 @@ def main(argv=None) -> None:
     ap.add_argument("--scale", type=float, default=0.25,
                     help="graph size multiplier vs DESIGN.md defaults")
     ap.add_argument("--quick", action="store_true", help="partition metrics only")
-    ap.add_argument("--skip-roofline", action="store_true")
     # Names are validated against the repro.api registry after parsing, so
     # `--help` / usage errors stay import-cheap (no jax load).
     ap.add_argument("--partitioners", nargs="+", metavar="NAME", default=None,
@@ -42,7 +41,7 @@ def main(argv=None) -> None:
     if bad:
         ap.error(f"unknown compute backend(s) {bad}; valid: {list(COMPUTE_BACKENDS)}")
 
-    from benchmarks import breakdown, messages, partition_tables, runtime, roofline
+    from benchmarks import messages, partition_tables, runtime
 
     csv: list[tuple[str, float, str]] = []
 
@@ -80,18 +79,6 @@ def main(argv=None) -> None:
                 wall_o = max(row_o["ebg"]["wall_s"], 1e-3)
                 csv.append((f"backend_ab_{base}_vs_{other}[{key}/{algo}]", 0.0,
                             f"ebg_wall_speedup={wall_b / wall_o:.2f}x"))
-
-        t0 = time.time()
-        res2 = breakdown.main(min(args.scale, 0.25), partitioners=parts)
-        csv.append(("table2_fig5_breakdown", (time.time() - t0) * 1e6,
-                    f"ebg_exec={res2.get('ebg', {}).get('exec_time', float('nan')):.3f}s"))
-
-    if not args.skip_roofline:
-        try:
-            rows = roofline.main()
-            csv.append(("roofline_table", 0.0, f"cells={len(rows)}"))
-        except Exception as e:  # dry-run output not present yet
-            print(f"(roofline skipped: {e})")
 
     print("\nname,us_per_call,derived")
     for name, us, derived in csv:

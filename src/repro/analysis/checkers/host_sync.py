@@ -1,7 +1,7 @@
 """HS01 — host-sync leak inside traced (jitted / loop-body) code.
 
 The fused BSP drivers' headline invariant is ONE host sync per run
-(pinned at runtime by `engine.DISPATCH_COUNTS`). A `np.asarray`,
+(pinned at runtime by the `engine.dispatch.*` counters of `repro.obs`). A `np.asarray`,
 `.item()`, `float()`, `bool()` or `jax.device_get` on a traced value
 inside a `@jax.jit` function or a `lax.while_loop`/`lax.scan` body either
 breaks tracing outright (ConcretizationTypeError at the first run with a

@@ -33,6 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.api.config import PartitionerConfig, check_compute_backend
 from repro.api.registry import PartitionerSpec, check_num_parts, get_partitioner
 from repro.core.metrics import PartitionMetrics, partition_metrics
@@ -42,6 +43,7 @@ from repro.graph.build import SubgraphSet, build_subgraphs
 from repro.graph.engine import (
     BSPStats,
     VertexProgram,
+    _assemble_stats,
     _kernel_value_boundary,
     check_driver,
     check_int32_kernel_labels,
@@ -229,7 +231,8 @@ class GraphPipeline:
     def result(self) -> PartitionResult:
         st = self._stage()
         if st["result"] is None:
-            st["result"] = st["spec"].partition(self.graph, st["parts"], config=st["config"])
+            with obs.span("partition.run"):
+                st["result"] = st["spec"].partition(self.graph, st["parts"], config=st["config"])
         return st["result"]
 
     @property
@@ -350,16 +353,17 @@ class GraphPipeline:
                     "the fused while_loop stepper"
                 )
             kw["driver"] = driver
+        if mode not in ("sim", "dist"):
+            raise ValueError(f"unknown mode {mode!r}; expected 'sim' or 'dist'")
         sub = self.subgraphs_for(**self._build_params_for(prog, symmetrize, pad_multiple))
         src = self._source_for(prog, source)
-        if mode == "sim":
-            values, stats = alg.run_program(
-                sub, prog, num_vertices=self.graph.num_vertices, source=src, **kw
-            )
-        elif mode == "dist":
-            values, stats = self._run_distributed(prog, sub, source=src, **kw)
-        else:
-            raise ValueError(f"unknown mode {mode!r}; expected 'sim' or 'dist'")
+        with obs.span("engine.run"):
+            if mode == "sim":
+                values, stats = alg.run_program(
+                    sub, prog, num_vertices=self.graph.num_vertices, source=src, **kw
+                )
+            else:
+                values, stats = self._run_distributed(prog, sub, source=src, **kw)
         return PipelineRun(pipeline=self, program=prog.name, values=values, stats=stats, subgraphs=sub)
 
     def run_batch(
@@ -435,36 +439,34 @@ class GraphPipeline:
         if ndev != sub.num_parts:
             raise ValueError(f"mesh axes {axes} span {ndev} devices but partition has {sub.num_parts} parts")
         arrays, statics = subgraphs_to_arrays(sub)
-        stepper = make_distributed_stepper(
-            mesh, axes, prog, statics, num_supersteps=num_supersteps, inner_cap=inner_cap,
-            tol=tol, num_vertices=self.graph.num_vertices, compute_backend=compute_backend,
-            block_e=block_e,
-        )
-        init = prog.init(sub, num_vertices=self.graph.num_vertices, source=source)
-        # Two-level value boundary (host-side, before tracing): label-domain
-        # programs run on dense ranks so kernels never see raw global ids.
-        # Rank compression is order-preserving, so it commutes with the
-        # runner's internal max→min negation; output decodes below.
-        init, codec = _kernel_value_boundary(prog, sub, init, compute_backend)
-        with mesh:
-            val, msgs, steps, msgs_steps, iters_steps = jax.jit(stepper)(arrays, init)
+        with obs.span("engine.prepare"):
+            stepper = make_distributed_stepper(
+                mesh, axes, prog, statics, num_supersteps=num_supersteps, inner_cap=inner_cap,
+                tol=tol, num_vertices=self.graph.num_vertices, compute_backend=compute_backend,
+                block_e=block_e,
+            )
+            init = prog.init(sub, num_vertices=self.graph.num_vertices, source=source)
+            # Two-level value boundary (host-side, before tracing): label-domain
+            # programs run on dense ranks so kernels never see raw global ids.
+            # Rank compression is order-preserving, so it commutes with the
+            # runner's internal max→min negation; output decodes below.
+            init, codec = _kernel_value_boundary(prog, sub, init, compute_backend)
+        # A new jit of a new closure: every run traces and lowers again
+        # (counted by `engine.trace`), then loads its executable from the cache.
+        with obs.span("engine.dispatch"), mesh:
+            val, _, steps, msgs_steps, iters_steps = jax.jit(stepper)(arrays, init)
+        with obs.span("engine.fetch"):
+            steps = int(steps)
+            msgs_sw = np.asarray(msgs_steps, np.int64)[:steps]
+            iters_sw = np.asarray(iters_steps, np.int64)[:steps]
+            val = np.asarray(val)
         if codec is not None:
-            val = codec.decode(np.asarray(val))  # gathered: the table is unsharded
-        steps = int(steps)
-        msgs_sw = np.asarray(msgs_steps, np.int64)[:steps]
-        iters_sw = np.asarray(iters_steps, np.int64)[:steps]
+            val = codec.decode(val)  # gathered: the table is unsharded
         # Per-worker compute work from the returned inner-iteration buffer ×
-        # per-worker edge counts — the same formula the sim drivers use, so
+        # per-worker edge counts — the same assembly the sim drivers use, so
         # sim and dist stats agree exactly.
         edges = np.asarray(sub.edge_mask.sum(axis=1), np.int64)
-        stats = BSPStats(
-            supersteps=steps,
-            messages_per_worker=np.asarray(msgs, np.int64),
-            messages_per_step=msgs_sw.sum(axis=1),
-            comp_work_per_worker=(iters_sw * edges[None, :]).sum(axis=0),
-            inner_iters_per_step=iters_sw,
-            messages_per_step_worker=msgs_sw,
-        )
+        stats = _assemble_stats(steps, msgs_sw, iters_sw, edges, prog, inner_cap)
         return np.asarray(val[:, :-1]), stats
 
     # --------------------------------------------------------------- lower

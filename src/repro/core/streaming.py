@@ -57,6 +57,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.api.config import EBGConfig, GreedyConfig, HDRFConfig, check_compute_backend
 from repro.api.registry import register_partitioner
 from repro.core.order import degree_sum_order
@@ -424,12 +425,13 @@ def _streaming_chunked(
         # streams; assignments stay identical to the dense path because
         # membership is pinned to block-start state and the commit
         # arithmetic is term-for-term the same.
-        keep, e_count, v_count, part = ops.ebg_commit_block(
-            jnp.zeros((p, (num_vertices + 31) // 32), dtype=jnp.uint32), e0, v0,
-            *blocks[:3], alpha=ce, beta=cv, inv_e=inv_e, inv_v=inv_v, eps=eps,
-            balance=balance, wu=blocks[3] if weighted else None,
-            wv=blocks[4] if weighted else None, impl=backend, window=window,
-        )
+        with jax.named_scope("ebg.commit"):
+            keep, e_count, v_count, part = ops.ebg_commit_block(
+                jnp.zeros((p, (num_vertices + 31) // 32), dtype=jnp.uint32), e0, v0,
+                *blocks[:3], alpha=ce, beta=cv, inv_e=inv_e, inv_v=inv_v, eps=eps,
+                balance=balance, wu=blocks[3] if weighted else None,
+                wv=blocks[4] if weighted else None, impl=backend, window=window,
+            )
         return part.reshape(-1), keep, e_count, v_count
 
     # Dense (p, V) bool membership table, batched gathers for the score
@@ -460,7 +462,8 @@ def _streaming_chunked(
         return (keep, e_count, v_count), parts
 
     keep0 = jnp.zeros((p, num_vertices), dtype=jnp.bool_)
-    (keep, e_count, v_count), part = jax.lax.scan(step, (keep0, e0, v0), tuple(blocks))
+    with jax.named_scope("ebg.commit"):
+        (keep, e_count, v_count), part = jax.lax.scan(step, (keep0, e0, v0), tuple(blocks))
     return part.reshape(-1), keep, e_count, v_count
 
 
@@ -500,47 +503,56 @@ def streaming_chunked_partition(
     ce, cv, eps = sc.coefficients(ce, cv, eps)
     if sort_edges is None:
         sort_edges = sc.sort_edges
-    src = np.asarray(graph.src, dtype=np.int32)
-    dst = np.asarray(graph.dst, dtype=np.int32)
-    # Validate BEFORE reorder and BEFORE the masked self-loop padding below
-    # (pad rows are synthetic and exempt); rows are named in input order.
-    validate_edge_stream(src, dst, num_vertices=graph.num_vertices)
-    order = degree_sum_order(graph) if sort_edges else None
-    if order is not None:
-        src, dst = src[order], dst[order]
-    w = edge_weights_np(sc, graph, src, dst)
-    E = src.shape[0]
-    pad = (-E) % block
-    valid = np.ones((E + pad,), bool)
-    if pad:
-        # Pad with self-loops on vertex 0, masked out of the commit loop
-        # (and dropped from the result). Pad weights are never committed;
-        # 1.0 keeps the scored lanes finite.
-        src = np.concatenate([src, np.zeros((pad,), np.int32)])
-        dst = np.concatenate([dst, np.zeros((pad,), np.int32)])
-        valid[E:] = False
-        if w is not None:
-            one = np.ones((pad,), np.float32)
-            w = (np.concatenate([w[0], one]), np.concatenate([w[1], one]))
-    zero = jnp.zeros((0,), jnp.float32)
-    part, _, _, _ = _streaming_chunked(
-        jnp.asarray(src),
-        jnp.asarray(dst),
-        jnp.asarray(valid),
-        zero if w is None else jnp.asarray(w[0]),
-        zero if w is None else jnp.asarray(w[1]),
-        jnp.float32(E),
-        num_parts=num_parts,
-        num_vertices=graph.num_vertices,
-        block=block,
-        backend=compute_backend,
-        weighted=sc.weighted,
-        balance=sc.balance,
-        ce=ce,
-        cv=cv,
-        eps=eps,
-        window=commit == "window",
-    )
+    # Host phases under `partition.*` spans (`repro.obs`): validate, the
+    # paper's degree-sum order (§IV-C), upload, then the commit launch.
+    with obs.span("partition.validate"):
+        src = np.asarray(graph.src, dtype=np.int32)
+        dst = np.asarray(graph.dst, dtype=np.int32)
+        # Validate BEFORE reorder and BEFORE the masked self-loop padding below
+        # (pad rows are synthetic and exempt); rows are named in input order.
+        validate_edge_stream(src, dst, num_vertices=graph.num_vertices)
+    with obs.span("partition.order"):
+        order = degree_sum_order(graph) if sort_edges else None
+        if order is not None:
+            src, dst = src[order], dst[order]
+    with obs.span("partition.upload"):
+        w = edge_weights_np(sc, graph, src, dst)
+        E = src.shape[0]
+        pad = (-E) % block
+        valid = np.ones((E + pad,), bool)
+        if pad:
+            # Pad with self-loops on vertex 0, masked out of the commit loop
+            # (and dropped from the result). Pad weights are never committed;
+            # 1.0 keeps the scored lanes finite.
+            src = np.concatenate([src, np.zeros((pad,), np.int32)])
+            dst = np.concatenate([dst, np.zeros((pad,), np.int32)])
+            valid[E:] = False
+            if w is not None:
+                one = np.ones((pad,), np.float32)
+                w = (np.concatenate([w[0], one]), np.concatenate([w[1], one]))
+        zero = jnp.zeros((0,), jnp.float32)
+        streams = (
+            jnp.asarray(src),
+            jnp.asarray(dst),
+            jnp.asarray(valid),
+            zero if w is None else jnp.asarray(w[0]),
+            zero if w is None else jnp.asarray(w[1]),
+            jnp.float32(E),
+        )
+    with obs.span("partition.commit"):  # returns once the device work is launched
+        part, _, _, _ = _streaming_chunked(
+            *streams,
+            num_parts=num_parts,
+            num_vertices=graph.num_vertices,
+            block=block,
+            backend=compute_backend,
+            weighted=sc.weighted,
+            balance=sc.balance,
+            ce=ce,
+            cv=cv,
+            eps=eps,
+            window=commit == "window",
+        )
     part = part[:E]
     return PartitionResult(part=part, num_parts=num_parts, order=order)
 

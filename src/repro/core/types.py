@@ -14,6 +14,8 @@ from typing import Optional
 import jax
 import numpy as np
 
+from repro import obs
+
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
@@ -74,12 +76,13 @@ class PartitionResult:
 
     def part_in_input_order(self) -> np.ndarray:
         """Per-edge assignment aligned with the original edge list."""
-        part = np.asarray(self.part)
-        if self.order is None:
-            return part
-        out = np.empty_like(part)
-        out[np.asarray(self.order)] = part
-        return out
+        with obs.span("partition.fetch"):
+            part = np.asarray(self.part)  # waits for the partitioner's device work
+            if self.order is None:
+                return part
+            out = np.empty_like(part)
+            out[np.asarray(self.order)] = part
+            return out
 
 
 def edge_weights_placeholder(num_edges: int) -> np.ndarray:

@@ -11,6 +11,7 @@ import dataclasses
 
 import numpy as np
 
+from repro import obs
 from repro.core.types import Graph
 from repro.graph.build import SubgraphSet
 from repro.graph.engine import BFS, CC, PR, REACH, SSSP, BSPStats, run_bsp
@@ -24,7 +25,8 @@ def run_program(
     """Run any `VertexProgram` (instance or registered name) and return
     values indexed by (part, local) with the dump slot stripped."""
     val, stats = run_bsp(sub, program, num_vertices=num_vertices, source=source, **kw)
-    return np.asarray(val[:, :-1]), stats
+    with obs.span("engine.fetch"):
+        return np.asarray(val[:, :-1]), stats
 
 
 def connected_components(sub: SubgraphSet, **kw) -> tuple[np.ndarray, BSPStats]:

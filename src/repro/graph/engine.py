@@ -38,7 +38,6 @@ negated on entry and on exit, so the superstep body only ever sees the
 """
 from __future__ import annotations
 
-import collections
 import dataclasses
 import functools
 import time
@@ -49,6 +48,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from repro import obs
 from repro.api.config import check_compute_backend
 from repro.core.metrics import max_mean_ratio
 from repro.graph.build import SubgraphSet, check_addressing
@@ -62,12 +62,6 @@ INF_I32 = jnp.int32(2**31 - 1)
 # "host" runs one jitted superstep per Python iteration (kept for A/B and as
 # the readable reference of the loop semantics).
 DRIVERS = ("fused", "host")
-
-# Device-program dispatch accounting for the sim drivers: keys "fused" /
-# "host", incremented once per jitted call. tests/test_drivers.py pins the
-# fused drivers to exactly one dispatch per run with this counter.
-DISPATCH_COUNTS: collections.Counter = collections.Counter()
-
 
 def check_driver(driver) -> str:
     if driver not in DRIVERS:
@@ -87,6 +81,9 @@ class BSPStats:
     # messages_per_step above are its marginals, kept for existing call
     # sites; every driver populates all three.
     messages_per_step_worker: np.ndarray
+    # Local relaxation passes of the whole run: how many times the local
+    # stage swept the edge slots (`relax_passes`, below).
+    relax_passes: int = 0
 
     @property
     def total_messages(self) -> int:
@@ -534,7 +531,9 @@ def _superstep(
     (new_val, per-worker msg count, per-worker inner iters, L1 delta).
 
     Stages: local compute → mirror→master exchange + combine → apply →
-    master→mirror broadcast. `count_ref` is the value snapshot of the LAST
+    master→mirror broadcast, under the named scopes `bsp.local`,
+    `bsp.exchange` (both exchanges) and `bsp.apply`, so a profile can tell
+    them apart. `count_ref` is the value snapshot of the LAST
     exchange — delta messages are counted against it (matters under bounded
     staleness). The L1 delta is only materialized for convergence='tol'
     programs (a zero scalar otherwise).
@@ -545,45 +544,49 @@ def _superstep(
     # 1. local compute. Fixpoint programs carry the value itself; sweep
     # programs carry the per-vertex partial aggregate (one sweep = one
     # inner iteration of comp work per worker).
-    if prog.local == "fixpoint":
-        state, iters = _local_fixpoint(prog, sub, val, inner_cap, backend, interpret, block_e)
-    else:
-        state = _local_sweep(prog, sub, val, backend, interpret, block_e)
-        iters = jnp.ones((p,), jnp.int32)
+    with jax.named_scope("bsp.local"):
+        if prog.local == "fixpoint":
+            state, iters = _local_fixpoint(prog, sub, val, inner_cap, backend, interpret, block_e)
+        else:
+            state = _local_sweep(prog, sub, val, backend, interpret, block_e)
+            iters = jnp.ones((p,), jnp.int32)
     if not do_exchange:  # bounded-staleness local step (straggler mitigation)
         return state, jnp.zeros((p,), jnp.int32), iters, jnp.float32(0.0)
 
     # 2. mirror → master (forward): send current state of mirror slots.
-    S = _gather_rows(state, sub.send_idx)  # [i, j, m]
-    if prog.message_policy == "delta":
-        changed = state != start
-        ch_send = jnp.take_along_axis(changed, sub.send_idx.reshape(p, -1), axis=1).reshape(
-            sub.send_idx.shape
-        )
-        msgs_fwd = jnp.sum(ch_send & sub.msg_mask, axis=(1, 2))
-    else:
-        msgs_fwd = jnp.sum(sub.msg_mask, axis=(1, 2))
-    R = exchange(S)  # receiver-rowed [j, i, m]
-    upd = jnp.where(sub.recv_mask, R, prog.identity)
-    if prog.combine == "sum":
-        combined = _scatter_add(state, sub.recv_idx, upd)
-    else:
-        combined = _scatter_min(state, sub.recv_idx, upd)
+    with jax.named_scope("bsp.exchange"):
+        S = _gather_rows(state, sub.send_idx)  # [i, j, m]
+        if prog.message_policy == "delta":
+            changed = state != start
+            ch_send = jnp.take_along_axis(
+                changed, sub.send_idx.reshape(p, -1), axis=1
+            ).reshape(sub.send_idx.shape)
+            msgs_fwd = jnp.sum(ch_send & sub.msg_mask, axis=(1, 2))
+        else:
+            msgs_fwd = jnp.sum(sub.msg_mask, axis=(1, 2))
+        R = exchange(S)  # receiver-rowed [j, i, m]
+        upd = jnp.where(sub.recv_mask, R, prog.identity)
+        if prog.combine == "sum":
+            combined = _scatter_add(state, sub.recv_idx, upd)
+        else:
+            combined = _scatter_min(state, sub.recv_idx, upd)
 
     # 3. apply at masters, then master → mirror (broadcast).
-    new_val = _apply_step(prog, sub, combined, num_vertices)
-    B = _gather_rows(new_val, sub.recv_idx)  # [j, i, m] master values
-    if prog.message_policy == "delta":
-        ch_master = new_val != start
-        ch_b = jnp.take_along_axis(
-            ch_master, sub.recv_idx.reshape(p, -1), axis=1
-        ).reshape(sub.recv_idx.shape)
-        msgs_bwd = jnp.sum(ch_b & sub.recv_mask, axis=(1, 2))
-    else:
-        msgs_bwd = jnp.sum(sub.recv_mask, axis=(1, 2))
-    Rb = exchange(B)  # sender-rowed view at mirrors: [i, j, m]
-    idx_masked = jnp.where(sub.msg_mask, sub.send_idx, sub.max_v)
-    out = _scatter_set(new_val, idx_masked, Rb)
+    with jax.named_scope("bsp.apply"):
+        new_val = _apply_step(prog, sub, combined, num_vertices)
+    with jax.named_scope("bsp.exchange"):
+        B = _gather_rows(new_val, sub.recv_idx)  # [j, i, m] master values
+        if prog.message_policy == "delta":
+            ch_master = new_val != start
+            ch_b = jnp.take_along_axis(
+                ch_master, sub.recv_idx.reshape(p, -1), axis=1
+            ).reshape(sub.recv_idx.shape)
+            msgs_bwd = jnp.sum(ch_b & sub.recv_mask, axis=(1, 2))
+        else:
+            msgs_bwd = jnp.sum(sub.recv_mask, axis=(1, 2))
+        Rb = exchange(B)  # sender-rowed view at mirrors: [i, j, m]
+        idx_masked = jnp.where(sub.msg_mask, sub.send_idx, sub.max_v)
+        out = _scatter_set(new_val, idx_masked, Rb)
 
     if prog.convergence == "tol":
         delta = jnp.abs(out[:, : sub.max_v] - val[:, : sub.max_v]).sum()
@@ -744,6 +747,7 @@ def _sim_exchange(S: jax.Array) -> jax.Array:
 )
 def _jit_superstep_sim(prog, sub, val, inner_cap, do_exchange, count_ref, num_vertices=0,
                        backend="xla", block_e=512):
+    obs.count("engine.trace")  # runs only while JAX traces
     return _superstep(
         prog, sub, val, _sim_exchange, inner_cap, do_exchange, count_ref, num_vertices, backend,
         block_e=block_e,
@@ -776,6 +780,7 @@ def _fused_bsp(sub, val, *, prog, max_supersteps, inner_cap, exchange_period, to
     # counts, and convergence are bit-identical to the in-loop remap (and
     # the host driver, which still pays it per superstep in
     # `_local_fixpoint`). Pinned by test_fused_no_inloop_remap.
+    obs.count("engine.trace")  # runs only while JAX traces
     to_f32 = backend != "xla" and prog.dtype == "int32"
     if to_f32:
         val = jnp.where(val == INF_I32, INF_F32, val.astype(jnp.float32))
@@ -838,8 +843,32 @@ def _fused_bsp(sub, val, *, prog, max_supersteps, inner_cap, exchange_period, to
     return val, steps, converged, msgs_buf, iters_buf, edges
 
 
+def relax_passes(prog: VertexProgram, iters_sw: np.ndarray, inner_cap: int) -> int:
+    """Local relaxation passes of a run, from its [steps, p] inner-iteration
+    counts: how many times the local stage swept the edge slots.
+
+    A sweep program makes one pass per superstep. A fixpoint program's
+    workers relax independently inside the local loop (a worker's pass
+    reads only its own values), so a worker that stops changing stays
+    unchanged, and `iters[s, w]` — the passes in which w changed — are the
+    first passes of superstep s. The batched `while_loop` stops after the
+    first pass in which no worker changed, or at `inner_cap`: it runs
+    `min(max_w iters[s, w] + 1, inner_cap)` passes. Counted on the host at
+    stats assembly, the same way for the sim, dist and batched drivers (a
+    dist device loops over its own workers only, so the count is the
+    busiest device's). The kernel backends loop each worker to its own
+    count; the number is the XLA loop's all the same.
+    """
+    steps = iters_sw.shape[0]
+    if prog.local != "fixpoint":
+        return int(steps)
+    if steps == 0:
+        return 0
+    return int(np.minimum(iters_sw.max(axis=1) + 1, inner_cap).sum())
+
+
 def _assemble_stats(steps: int, msgs_sw: np.ndarray, iters_sw: np.ndarray,
-                    edges: np.ndarray) -> BSPStats:
+                    edges: np.ndarray, prog: VertexProgram, inner_cap: int) -> BSPStats:
     return BSPStats(
         supersteps=steps,
         messages_per_worker=msgs_sw.sum(axis=0),
@@ -847,6 +876,7 @@ def _assemble_stats(steps: int, msgs_sw: np.ndarray, iters_sw: np.ndarray,
         comp_work_per_worker=(iters_sw * edges[None, :]).sum(axis=0),
         inner_iters_per_step=iters_sw,
         messages_per_step_worker=msgs_sw,
+        relax_passes=relax_passes(prog, iters_sw, inner_cap),
     )
 
 
@@ -936,33 +966,36 @@ def run_bsp(
             f"exchange_period>1 (bounded staleness) needs a fixpoint/no-change program; "
             f"{prog.name!r} is local={prog.local!r}, convergence={prog.convergence!r}"
         )
-    if init_val is None:
-        init_val = prog.init(sub, num_vertices=num_vertices, source=source)
-    # Max-combine runs as min over negated values (kernel reuse); delta
-    # message counts and no-change convergence are negation-invariant.
-    exec_prog, negate = _exec_view(prog)
-    val = -init_val if negate else init_val
-    # Two-level runs rank-compress label-domain values here so the kernels
-    # only ever see ranks < 2^24; codec=None means values pass raw.
-    val, codec = _kernel_value_boundary(prog, sub, val, compute_backend)
+    with obs.span("engine.prepare"):
+        if init_val is None:
+            init_val = prog.init(sub, num_vertices=num_vertices, source=source)
+        # Max-combine runs as min over negated values (kernel reuse); delta
+        # message counts and no-change convergence are negation-invariant.
+        exec_prog, negate = _exec_view(prog)
+        val = -init_val if negate else init_val
+        # Two-level runs rank-compress label-domain values here so the kernels
+        # only ever see ranks < 2^24; codec=None means values pass raw.
+        val, codec = _kernel_value_boundary(prog, sub, val, compute_backend)
     p = val.shape[0]
 
     if driver == "fused":
-        val, steps, _, msgs_buf, iters_buf, edges = _fused_bsp(
-            sub,
-            val,
-            prog=exec_prog,
-            max_supersteps=max_supersteps,
-            inner_cap=inner_cap,
-            exchange_period=exchange_period,
-            tol=tol,
-            num_vertices=num_vertices,
-            backend=compute_backend,
-            block_e=block_e,
-        )
-        DISPATCH_COUNTS["fused"] += 1
+        with obs.span("engine.dispatch"):
+            val, steps, _, msgs_buf, iters_buf, edges = _fused_bsp(
+                sub,
+                val,
+                prog=exec_prog,
+                max_supersteps=max_supersteps,
+                inner_cap=inner_cap,
+                exchange_period=exchange_period,
+                tol=tol,
+                num_vertices=num_vertices,
+                backend=compute_backend,
+                block_e=block_e,
+            )
+        obs.count("engine.dispatch.fused")
         # The run's single host sync: one device_get for every stat buffer.
-        steps, msgs_sw, iters_sw, edges = jax.device_get((steps, msgs_buf, iters_buf, edges))
+        with obs.span("engine.fetch"):
+            steps, msgs_sw, iters_sw, edges = jax.device_get((steps, msgs_buf, iters_buf, edges))
         steps = int(steps)
         if codec is not None:
             val = codec.decode(val)
@@ -971,6 +1004,8 @@ def run_bsp(
             msgs_sw[:steps].astype(np.int64),
             iters_sw[:steps].astype(np.int64),
             edges.astype(np.int64),
+            exec_prog,
+            inner_cap,
         )
 
     msg_steps = []
@@ -985,7 +1020,7 @@ def run_bsp(
             exec_prog, sub, val, inner_cap, do_exchange, last_exchanged,
             num_vertices, compute_backend, block_e,
         )
-        DISPATCH_COUNTS["host"] += 1
+        obs.count("engine.dispatch.host")
         if do_exchange:
             last_exchanged = val
         steps += 1
@@ -1001,7 +1036,9 @@ def run_bsp(
     iters_sw = np.asarray(iters_steps).reshape(steps, p)
     if codec is not None:
         val = codec.decode(val)
-    return (-val if negate else val), _assemble_stats(steps, msgs_sw, iters_sw, edges)
+    return (-val if negate else val), _assemble_stats(
+        steps, msgs_sw, iters_sw, edges, exec_prog, inner_cap
+    )
 
 
 # ----------------------------------------------- batched fused sim driver
@@ -1026,6 +1063,7 @@ def _fused_bsp_batch(sub, vals, *, prog, max_supersteps, inner_cap, tol, num_ver
                      block_e=512):
     # Same run-boundary hoist of the kernel path's int32<->f32 remap as
     # `_fused_bsp` (bijective, so per-query values/stats are unchanged).
+    obs.count("engine.trace")  # runs only while JAX traces
     to_f32 = backend != "xla" and prog.dtype == "int32"
     if to_f32:
         vals = jnp.where(vals == INF_I32, INF_F32, vals.astype(jnp.float32))
@@ -1106,7 +1144,7 @@ def batch_init(prog, sub: SubgraphSet, sources=None, *, batch: Optional[int] = N
     return jnp.tile(one[None], (int(batch), 1, 1))
 
 
-def _assemble_batch_stats(steps_q, msgs_sbw, iters_sbw, edges) -> list:
+def _assemble_batch_stats(steps_q, msgs_sbw, iters_sbw, edges, prog, inner_cap) -> list:
     """Per-query BSPStats from the batched [S, B, p] buffers: query b's
     series is truncated to the supersteps IT paid under masking."""
     edges = edges.astype(np.int64)
@@ -1116,6 +1154,8 @@ def _assemble_batch_stats(steps_q, msgs_sbw, iters_sbw, edges) -> list:
             msgs_sbw[: int(steps_q[b]), b].astype(np.int64),
             iters_sbw[: int(steps_q[b]), b].astype(np.int64),
             edges,
+            prog,
+            inner_cap,
         )
         for b in range(msgs_sbw.shape[1])
     ]
@@ -1176,11 +1216,13 @@ def run_bsp_batch(
         sub, vals, prog=exec_prog, max_supersteps=max_supersteps, inner_cap=inner_cap,
         tol=tol, num_vertices=num_vertices, backend=compute_backend, block_e=block_e,
     )
-    DISPATCH_COUNTS["batch"] += 1
+    obs.count("engine.dispatch.batch")
     steps_q, msgs_sbw, iters_sbw, edges = jax.device_get((steps_q, msgs_buf, iters_buf, edges))
     if codec is not None:
         vals = codec.decode(vals)
-    return (-vals if negate else vals), _assemble_batch_stats(steps_q, msgs_sbw, iters_sbw, edges)
+    return (-vals if negate else vals), _assemble_batch_stats(
+        steps_q, msgs_sbw, iters_sbw, edges, exec_prog, inner_cap
+    )
 
 
 @dataclasses.dataclass
@@ -1201,6 +1243,7 @@ class BatchExecutable:
     compiled: object
     compile_s: float
     compute_backend: str = "xla"
+    inner_cap: int = 10_000
 
     def run(self, init_vals: jax.Array) -> tuple[jax.Array, list]:
         """Same contract as `run_bsp_batch` (init_vals is donated)."""
@@ -1217,7 +1260,7 @@ class BatchExecutable:
             self.program, self.sub, vals, self.compute_backend
         )
         vals, steps_q, msgs_buf, iters_buf, edges = self.compiled(self.sub, vals)
-        DISPATCH_COUNTS["batch"] += 1
+        obs.count("engine.dispatch.batch")
         steps_q, msgs_sbw, iters_sbw, edges = jax.device_get(
             (steps_q, msgs_buf, iters_buf, edges)
         )
@@ -1225,7 +1268,9 @@ class BatchExecutable:
             vals = codec.decode(vals)
         return (
             -vals if self.negate else vals
-        ), _assemble_batch_stats(steps_q, msgs_sbw, iters_sbw, edges)
+        ), _assemble_batch_stats(
+            steps_q, msgs_sbw, iters_sbw, edges, self.program, self.inner_cap
+        )
 
 
 def compile_batch_executable(
@@ -1259,6 +1304,7 @@ def compile_batch_executable(
     return BatchExecutable(
         program=prog, sub=sub, batch=int(batch), negate=negate, compiled=compiled,
         compile_s=time.perf_counter() - t0, compute_backend=compute_backend,
+        inner_cap=inner_cap,
     )
 
 
@@ -1352,6 +1398,7 @@ def make_distributed_stepper(
         return jnp.swapaxes(out, 0, 1)
 
     def stepper(arrays: dict, val: jax.Array):
+        obs.count("engine.trace")  # runs only while JAX traces
         sub = SubgraphSet(**arrays, **statics)
         if to_f32:
             val = jnp.where(val == INF_I32, INF_F32, val.astype(jnp.float32))

@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.checkpoint import ckpt
 from repro.graph import engine
 from repro.resilience.faults import FaultPlan, WorkerCrashError
@@ -109,7 +110,7 @@ def _run_fused_segment(sub, exec_prog, state: _SegState, seg: int, *, inner_cap,
         exchange_period=exchange_period, tol=tol, num_vertices=num_vertices,
         backend=compute_backend, block_e=block_e,
     )
-    engine.DISPATCH_COUNTS["fused"] += 1
+    obs.count("engine.dispatch.fused")
     val, steps, converged, msgs_sw, iters_sw = jax.device_get(
         (val, steps, converged, msgs_buf, iters_buf)
     )
@@ -136,7 +137,7 @@ def _run_host_segment(sub, exec_prog, state: _SegState, seg: int, *, inner_cap,
             exec_prog, sub, val, inner_cap, do_exchange, last_ex,
             num_vertices, compute_backend, block_e,
         )
-        engine.DISPATCH_COUNTS["host"] += 1
+        obs.count("engine.dispatch.host")
         if do_exchange:
             last_ex = val
         msg_steps.append(np.asarray(msgs, np.int64))
@@ -187,7 +188,7 @@ def _run_segments(sub, exec_prog, negate, state: _SegState, *, max_supersteps,
 
     msgs_sw, iters_sw = state.stack(p)
     edges = np.asarray(sub.edge_mask.sum(axis=1), np.int64)
-    stats = engine._assemble_stats(state.done, msgs_sw, iters_sw, edges)
+    stats = engine._assemble_stats(state.done, msgs_sw, iters_sw, edges, exec_prog, inner_cap)
     val = jnp.asarray(state.val)
     if state.codec is not None:
         val = state.codec.decode(val)

@@ -14,6 +14,7 @@ import numpy as np
 import pytest
 
 import repro.graph.engine as eng
+from repro import obs
 from repro.graph import algorithms as alg
 
 BACKENDS = ("xla", "ref", "pallas")
@@ -26,6 +27,7 @@ def assert_stats_equal(a: eng.BSPStats, b: eng.BSPStats):
     np.testing.assert_array_equal(a.messages_per_step_worker, b.messages_per_step_worker)
     np.testing.assert_array_equal(a.inner_iters_per_step, b.inner_iters_per_step)
     np.testing.assert_array_equal(a.comp_work_per_worker, b.comp_work_per_worker)
+    assert a.relax_passes == b.relax_passes
 
 
 @pytest.mark.parametrize("backend", BACKENDS)
@@ -80,18 +82,19 @@ def test_fused_driver_single_dispatch(built_small):
     g, sub, sub_dir = built_small
     # Warm the executable caches so the counted runs measure dispatches only.
     alg.connected_components(sub, driver="fused")
-    base_f, base_h = eng.DISPATCH_COUNTS["fused"], eng.DISPATCH_COUNTS["host"]
+    base = obs.counters()
     _, stats = alg.connected_components(sub, driver="fused")
-    assert eng.DISPATCH_COUNTS["fused"] - base_f == 1
-    assert eng.DISPATCH_COUNTS["host"] == base_h  # fused path never host-steps
+    seen = obs.counters() - base
+    assert seen["engine.dispatch.fused"] == 1
+    assert seen["engine.dispatch.host"] == 0  # fused path never host-steps
 
-    base_h = eng.DISPATCH_COUNTS["host"]
+    base = obs.counters()
     _, stats_h = alg.connected_components(sub, driver="host")
-    assert eng.DISPATCH_COUNTS["host"] - base_h == stats_h.supersteps
+    assert (obs.counters() - base)["engine.dispatch.host"] == stats_h.supersteps
 
-    base_f = eng.DISPATCH_COUNTS["fused"]
+    base = obs.counters()
     alg.pagerank(sub_dir, g.num_vertices, num_iters=5, driver="fused")
-    assert eng.DISPATCH_COUNTS["fused"] - base_f == 1
+    assert (obs.counters() - base)["engine.dispatch.fused"] == 1
 
 
 def _nested_jaxprs(v):

@@ -282,6 +282,7 @@ def stats_eq(a, b, what):
     np.testing.assert_array_equal(a.messages_per_step_worker, b.messages_per_step_worker, err_msg=what)
     np.testing.assert_array_equal(a.inner_iters_per_step, b.inner_iters_per_step, err_msg=what)
     np.testing.assert_array_equal(a.comp_work_per_worker, b.comp_work_per_worker, err_msg=what)
+    assert a.relax_passes == b.relax_passes, what
     assert a.comp_work_per_worker.sum() > 0, what  # the dist zeroing bug
 
 sim = pipe.run('pr', num_iters=10)

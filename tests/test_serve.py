@@ -15,6 +15,7 @@ import pytest
 
 import repro.graph.engine as eng
 import repro.serve.padding as padding
+from repro import obs
 from repro.graph import algorithms as alg
 from repro.serve.cache import ExecutableCache
 from repro.serve.padding import DEFAULT_BUCKETS, bucket_size, pad_batch_rows, pad_items, padding_waste
@@ -112,10 +113,10 @@ def test_batched_bad_source_fails_fast(built_small):
     dispatch happens — it cannot poison the rest of the batch."""
     g, _, sub = built_small
     good = _sources(g, 2)
-    before = eng.DISPATCH_COUNTS["batch"]
+    before = obs.counters()["engine.dispatch.batch"]
     with pytest.raises(ValueError, match=f"source={g.num_vertices}"):
         eng.run_bsp_batch(sub, "bfs", good + [g.num_vertices], num_vertices=g.num_vertices)
-    assert eng.DISPATCH_COUNTS["batch"] == before
+    assert obs.counters()["engine.dispatch.batch"] == before
 
 
 def test_batch_init_argument_errors(built_small):
@@ -199,11 +200,12 @@ def test_batch_single_dispatch(built_small):
     g, _, sub = built_small
     srcs = _sources(g, 3)
     eng.run_bsp_batch(sub, "bfs", srcs, num_vertices=g.num_vertices)  # warm
-    base = dict(eng.DISPATCH_COUNTS)
+    base = obs.counters()
     eng.run_bsp_batch(sub, "bfs", srcs, num_vertices=g.num_vertices)
-    assert eng.DISPATCH_COUNTS["batch"] == base["batch"] + 1
-    assert eng.DISPATCH_COUNTS["fused"] == base["fused"]
-    assert eng.DISPATCH_COUNTS["host"] == base["host"]
+    after = obs.counters()
+    assert after["engine.dispatch.batch"] == base["engine.dispatch.batch"] + 1
+    assert after["engine.dispatch.fused"] == base["engine.dispatch.fused"]
+    assert after["engine.dispatch.host"] == base["engine.dispatch.host"]
 
 
 # ------------------------------------------------------ AOT executables
