@@ -351,8 +351,51 @@ def _scatter_set(val: jax.Array, idx: jax.Array, upd: jax.Array) -> jax.Array:
     return val.at[rows, idx.reshape(p, -1)].set(upd.reshape(p, -1))
 
 
-def _segment_min(data, seg, num_segments):
-    return jax.ops.segment_min(data, seg, num_segments=num_segments, indices_are_sorted=True)
+def _sorted_segments(key: jax.Array, num_segments: int) -> tuple[jax.Array, jax.Array]:
+    """The graph-only half of `_sorted_segment_min` for one sorted key row
+    [E]: each slot's segment-start flag [E] (`key[e] != key[e-1]`, true at
+    e = 0) and each segment's last slot [num_segments], -1 where it is
+    empty — `searchsorted(key, u, side="right") - 1` where u has slots.
+
+    Only a segment's last slot writes its position; every other slot gets
+    an index of its own past the end, which the scatter drops, so the
+    indices are unique."""
+    last = jnp.concatenate([key[1:] != key[:-1], jnp.ones((1,), bool)])
+    slots = jnp.arange(key.shape[0], dtype=jnp.int32)
+    idx = jnp.where(last, key, num_segments + slots)
+    end = jnp.full((num_segments,), -1, jnp.int32).at[idx].set(
+        slots, mode="drop", unique_indices=True
+    )
+    return jnp.concatenate([jnp.ones((1,), bool), last[:-1]]), end
+
+
+def _sorted_segment_min(data: jax.Array, start: jax.Array, end: jax.Array) -> jax.Array:
+    """Per-segment minimum of one row [E] whose segment keys are sorted, by
+    a segmented min-scan along the slots read at each segment's last slot
+    (`start`, `end` from `_sorted_segments`). Min is exact in any order, so
+    this equals `jax.ops.segment_min` bit for bit, an empty segment reading
+    the dtype's largest value as it does there; unlike it, no pass
+    scatters into the [num_segments] output.
+
+    The scan combines (start, value) pairs by (fa | fb, vb if fb else
+    min(va, vb)), in log2(E) doubling steps (Hillis-Steele): after the step
+    of shift k each slot holds the combine of the 2k slots ending at it,
+    slots before the row's start reading as the identity. The TPU compiler
+    takes minutes over `jax.lax.associative_scan` at millions of slots and
+    seconds over this form."""
+    if jnp.issubdtype(data.dtype, jnp.integer):
+        identity = jnp.asarray(jnp.iinfo(data.dtype).max, data.dtype)
+    else:
+        identity = jnp.asarray(jnp.inf, data.dtype)
+    n = data.shape[0]
+    flag, run = start, data
+    k = 1
+    while k < n:
+        prev = jnp.concatenate([jnp.full((k,), identity), run[:-k]])
+        run = jnp.where(flag, run, jnp.minimum(prev, run))
+        flag = flag | jnp.concatenate([jnp.ones((k,), bool), flag[:-k]])
+        k *= 2
+    return jnp.where(end >= 0, run[jnp.maximum(end, 0)], identity)
 
 
 # -------------------------------------------------- local compute (stage 1)
@@ -376,25 +419,40 @@ def _add_saturating(prog: VertexProgram, data: jax.Array, w: jax.Array) -> jax.A
     return data + w
 
 
-def _relax_xla(prog: VertexProgram, sub: SubgraphSet, v: jax.Array) -> jax.Array:
-    """One local relaxation sweep via generic XLA segment ops."""
-    nseg = sub.max_v + 1
+def _local_segments(prog: VertexProgram, sub: SubgraphSet, backend: str):
+    """`_relax_xla`'s graph-only input: the `_sorted_segments` of every
+    worker's reduce keys — `ldst`, and `lsrc_s` for bidirectional programs
+    (else None) — or None where the local stage does not run `_relax_xla`.
+    The drivers compute it once per run, before their loops; in batched
+    runs it is shared by every query."""
+    if backend != "xla" or prog.local != "fixpoint":
+        return None
+    segments = jax.vmap(functools.partial(_sorted_segments, num_segments=sub.max_v + 1))
+    return segments(sub.ldst), (segments(sub.lsrc_s) if prog.bidirectional else None)
+
+
+def _relax_xla(prog: VertexProgram, sub: SubgraphSet, v: jax.Array, segs=None) -> jax.Array:
+    """One local relaxation pass: each edge slot reads its source's value,
+    and each vertex takes the minimum over its (sorted) slots by
+    `_sorted_segment_min`. `segs` is `_local_segments(prog, sub, "xla")`;
+    None computes it here, in the pass."""
+    if segs is None:
+        segs = _local_segments(prog, sub, "xla")
+    fwd, rev = segs
     inf = prog.inf
     data = jnp.take_along_axis(v, sub.lsrc, axis=1)
     w = _edge_addend(prog, sub.weight, v.dtype)
     if w is not None:
         data = _add_saturating(prog, data, w)
     data = jnp.where(sub.edge_mask, data, inf)
-    cand = jax.vmap(lambda d, s: _segment_min(d, s, nseg))(data, sub.ldst)
-    new = jnp.minimum(v, cand)
+    new = jnp.minimum(v, jax.vmap(_sorted_segment_min)(data, *fwd))
     if prog.bidirectional:
         data2 = jnp.take_along_axis(v, sub.ldst_s, axis=1)
         w2 = _edge_addend(prog, sub.weight_s, v.dtype)
         if w2 is not None:
             data2 = _add_saturating(prog, data2, w2)
         data2 = jnp.where(sub.edge_mask_s, data2, inf)
-        cand2 = jax.vmap(lambda d, s: _segment_min(d, s, nseg))(data2, sub.lsrc_s)
-        new = jnp.minimum(new, cand2)
+        new = jnp.minimum(new, jax.vmap(_sorted_segment_min)(data2, *rev))
     return new
 
 
@@ -429,10 +487,13 @@ def _local_fixpoint(
     backend: str = "xla",
     interpret: bool | None = None,
     block_e: int = 512,
+    segs=None,
 ):
     """Batched local fixpoint. val: [p, max_v+1] (last slot = dump).
 
-    backend "xla" runs generic segment ops; "ref"/"pallas" route the WHOLE
+    backend "xla" loops `_relax_xla` passes (`segs`, its graph-only input,
+    is computed here, before the loop, where the driver passes none);
+    "ref"/"pallas" route the WHOLE
     local stage (every relaxation pass + the per-worker convergence flag)
     through the `ops.bsp_superstep` megakernel in one launch. For int32
     programs (CC/BFS/REACH) the kernel path remaps INF_I32 <-> INF_F32 and
@@ -454,7 +515,9 @@ def _local_fixpoint(
             new_val = jnp.where(new_val >= INF_F32, INF_I32, new_val.astype(jnp.int32))
         return new_val, iters
 
-    relax = functools.partial(_relax_xla, prog, sub)
+    if segs is None:
+        segs = _local_segments(prog, sub, backend)
+    relax = functools.partial(_relax_xla, prog, sub, segs=segs)
 
     def body_count(carry):
         v, ch, it, iters = carry
@@ -526,6 +589,7 @@ def _superstep(
     backend: str = "xla",
     interpret: bool | None = None,
     block_e: int = 512,
+    segs=None,
 ):
     """ONE BSP superstep for ANY program. Returns
     (new_val, per-worker msg count, per-worker inner iters, L1 delta).
@@ -536,7 +600,8 @@ def _superstep(
     them apart. `count_ref` is the value snapshot of the LAST
     exchange — delta messages are counted against it (matters under bounded
     staleness). The L1 delta is only materialized for convergence='tol'
-    programs (a zero scalar otherwise).
+    programs (a zero scalar otherwise). `segs` is the run's
+    `_local_segments`, handed to the local stage.
     """
     p = val.shape[0]
     start = val if count_ref is None else count_ref
@@ -546,7 +611,9 @@ def _superstep(
     # inner iteration of comp work per worker).
     with jax.named_scope("bsp.local"):
         if prog.local == "fixpoint":
-            state, iters = _local_fixpoint(prog, sub, val, inner_cap, backend, interpret, block_e)
+            state, iters = _local_fixpoint(
+                prog, sub, val, inner_cap, backend, interpret, block_e, segs
+            )
         else:
             state = _local_sweep(prog, sub, val, backend, interpret, block_e)
             iters = jnp.ones((p,), jnp.int32)
@@ -788,6 +855,7 @@ def _fused_bsp(sub, val, *, prog, max_supersteps, inner_cap, exchange_period, to
     p = val.shape[0]
     msgs_buf = jnp.zeros((max_supersteps, p), jnp.int32)
     iters_buf = jnp.zeros((max_supersteps, p), jnp.int32)
+    segs = _local_segments(prog, sub, backend)  # graph-only: once per run
 
     def converged_flag(v, v2, do_ex, delta):
         if prog.convergence == "tol":
@@ -807,7 +875,7 @@ def _fused_bsp(sub, val, *, prog, max_supersteps, inner_cap, exchange_period, to
             # so the trace needs no branch or last-exchange select.
             v2, msgs, iters, delta = _superstep(
                 prog, sub, v, _sim_exchange, inner_cap, True, last_ex, num_vertices, backend,
-                block_e=block_e,
+                block_e=block_e, segs=segs,
             )
             converged = converged_flag(v, v2, jnp.bool_(True), delta)
             last_ex = v2
@@ -817,11 +885,11 @@ def _fused_bsp(sub, val, *, prog, max_supersteps, inner_cap, exchange_period, to
                 do_ex,
                 lambda v_, le: _superstep(
                     prog, sub, v_, _sim_exchange, inner_cap, True, le, num_vertices, backend,
-                    block_e=block_e,
+                    block_e=block_e, segs=segs,
                 ),
                 lambda v_, le: _superstep(
                     prog, sub, v_, _sim_exchange, inner_cap, False, le, num_vertices, backend,
-                    block_e=block_e,
+                    block_e=block_e, segs=segs,
                 ),
                 v, last_ex,
             )
@@ -1074,11 +1142,13 @@ def _fused_bsp_batch(sub, vals, *, prog, max_supersteps, inner_cap, tol, num_ver
     iters_buf = jnp.zeros((max_supersteps, B, p), jnp.int32)
     # Every step exchanges (exchange_period=1), so the delta-message
     # reference is the entry value itself — count_ref=None, as in the
-    # specialized period-1 branch of `_fused_bsp`.
+    # specialized period-1 branch of `_fused_bsp`. The graph-only segments
+    # are computed once, outside the vmap, and shared by every query.
+    segs = _local_segments(prog, sub, backend)
     vstep = jax.vmap(
         lambda v: _superstep(
             prog, sub, v, _sim_exchange, inner_cap, True, None, num_vertices, backend,
-            block_e=block_e,
+            block_e=block_e, segs=segs,
         )
     )
 
@@ -1405,6 +1475,7 @@ def make_distributed_stepper(
         nloc = val.shape[0]  # subgraphs per device (1 on a fully sharded mesh)
         msgs_buf = jnp.zeros((num_supersteps, nloc), jnp.int32)
         iters_buf = jnp.zeros((num_supersteps, nloc), jnp.int32)
+        segs = _local_segments(exec_prog, sub, compute_backend)  # once per run
 
         def cond(carry):
             _, k, done, _, _ = carry
@@ -1415,7 +1486,7 @@ def make_distributed_stepper(
             v2, m, it, delta = _superstep(
                 exec_prog, sub, v, a2a_exchange, inner_cap,
                 num_vertices=num_vertices, backend=compute_backend, interpret=interpret,
-                block_e=block_e,
+                block_e=block_e, segs=segs,
             )
             # Convergence is global: psum the per-device signal so every
             # device takes the same trip count (collectives stay uniform).
