@@ -6,11 +6,8 @@ For each seed this runs the cell as the benchmark does, then again with
 the control in the program's place, and prints both runs' compared numbers
 (one JSON line each). The benchmark's own runs never run a control.
 
-- `hops` (BFS): the reference with one guarantee broken, BFS over the
-  directed arcs only (no symmetrization).
-- `rank` (PageRank, float32 ranks): the power method in bfloat16.
-- `ebv` (partition): the program's own approximate path, `commit="frozen"`,
-  which scores a whole block against block-start membership.
+Each traffic mix's compare file, `bench/compares/<compare>.py`, holds its
+control (`install_control`) beside its check and says what it breaks.
 """
 from __future__ import annotations
 
@@ -18,48 +15,22 @@ import argparse
 import json
 import sys
 
-import numpy as np
-
-
-def pagerank_lowp(src, dst, n: int, *, damping: float, num_iters: int, dtype) -> np.ndarray:
-    """The reference power method with every value in `dtype`."""
-    import jax
-    import jax.numpy as jnp
-
-    src, dst = jnp.asarray(src), jnp.asarray(dst)
-    outdeg = jnp.zeros((n,), dtype).at[src].add(1)
-    share_of = jnp.where(outdeg > 0, 1 / jnp.maximum(outdeg, 1), 0).astype(dtype)
-    rank = jnp.full((n,), 1 / n, dtype)
-    for _ in range(num_iters):
-        agg = jax.ops.segment_sum((rank * share_of)[src], dst, num_segments=n)
-        rank = ((1 - damping) / n + damping * agg).astype(dtype)
-    return np.asarray(rank.astype(jnp.float32), np.float64)
-
 
 def install(jobs) -> None:
-    """Put the control in `jobs`' place (the hook of `bench.run.run_cell`)."""
-    import jax.numpy as jnp
+    """Put the control of the traffic's compare in `jobs`' place (the hook
+    of `bench.run.run_cell`)."""
+    jobs.compare.install_control(jobs)
 
-    from bench import reference
 
-    d, t = jobs.data, jobs.traffic
-    if jobs.kind == "partition":
-        jobs.config = dict(jobs.config, partitioner=dict(jobs.config["partitioner"], commit="frozen"))
-        return
+def answer_with(jobs, answer) -> None:
+    """Make each engine job answer `answer(root)`, a per-vertex array, in
+    place of the program's values; the program still runs each job, so the
+    window and the counts stay the program's."""
     program_run = jobs.run
-    compare = t["compare"]
-    if compare == "hops":
-        adj = reference.csr(d.src, d.dst, d.num_vertices)
-        control = lambda root: reference.hops(adj, root)
-    elif compare == "rank":
-        ranks = pagerank_lowp(d.src, d.dst, d.num_vertices, dtype=jnp.bfloat16, **t["reference"])
-        control = lambda root: ranks
-    else:
-        raise KeyError(f"no control for compare {compare!r}")
 
     def run(i):
         _, stats, root = program_run(i)
-        return control(root), stats, root
+        return answer(root), stats, root
 
     jobs.run = run
 

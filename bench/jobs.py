@@ -1,18 +1,21 @@
-"""The jobs a traffic mix runs, and the comparison that decides `correct`.
+"""The jobs a traffic mix runs.
 
 A traffic file names its `kind`:
 
 - `engine`: set-up partitions and builds the configuration's graph once;
   each job is one `GraphPipeline.run` of the traffic's `program`, from the
-  call to its values on the host. Source programs take `roots` roots from
-  the seed, among vertices of nonzero degree (Graph500's search keys), and
-  cycle through them.
+  call to its values on the host. Source programs cycle through the
+  traffic's `roots`: a count, drawn from the seed among vertices of nonzero
+  degree (Graph500's search keys), or a list of vertex ids, taken in its
+  order.
 - `partition`: each job partitions the graph's host edge arrays on a fresh
   `GraphPipeline`, from the call to the assignment on the host.
 
-`check` compares what the window's jobs produced with `bench.reference`,
-after the program's state is freed. Each comparison gives one number per
-traffic `compare` name; the traffic file holds its limit.
+A traffic file also names its `compare`, `bench/compares/<compare>.py`:
+its `check(jobs, records, maps, seed)` compares what the window's jobs
+produced with `bench.reference`, after the program's state is freed, and
+gives the numbers that the traffic's `limits` hold; its
+`install_control(jobs)` puts the control in the program's place.
 """
 from __future__ import annotations
 
@@ -22,10 +25,7 @@ from typing import Optional
 
 import numpy as np
 
-from bench import reference, work
-
-# The engine's "unreached" hop count (repro.graph.engine.INF_I32).
-UNREACHED_HOPS = 2**31 - 1
+from bench import work
 
 
 @dataclasses.dataclass
@@ -66,7 +66,35 @@ def _partition_kwargs(config: dict) -> tuple[str, dict]:
     return spec.pop("name"), spec
 
 
-class EngineJobs:
+def draw_roots(spec, data: GraphData, seed: int) -> Optional[np.ndarray]:
+    """A traffic's `roots`: `<count>` distinct vertices of nonzero degree
+    drawn from the seed, or a list of vertex ids taken as it is. A listed id
+    outside the graph or of zero degree is refused."""
+    if isinstance(spec, list):
+        if not spec:
+            raise ValueError("roots: the list is empty")
+        degrees = data.degrees()
+        for v in spec:
+            if not isinstance(v, int) or not 0 <= v < data.num_vertices:
+                raise ValueError(f"root {v!r} is not a vertex id in [0, {data.num_vertices})")
+            if degrees[v] == 0:
+                raise ValueError(f"root {v} has degree 0")
+        return np.asarray(spec, np.int64)
+    if not spec:
+        return None
+    return np.random.default_rng(seed).choice(data.covered(), size=int(spec), replace=False)
+
+
+class Jobs:
+    """What both kinds share: the traffic's compare module decides `correct`."""
+
+    compare = None  # the module `bench/compares/<compare>.py`
+
+    def check(self, records: list, maps: dict, seed: int) -> dict:
+        return self.compare.check(self, records, maps, seed)
+
+
+class EngineJobs(Jobs):
     """Partition and build once; each job is one engine run."""
 
     kind = "engine"
@@ -77,6 +105,7 @@ class EngineJobs:
         from repro.api import GraphPipeline
 
         self.data, self.config, self.traffic = data, config, traffic
+        self.roots = draw_roots(traffic.get("roots"), data, seed)
         self.program = traffic["program"]
         self.symmetrize = bool(traffic.get("symmetrize", False))
         self.kw = dict(traffic.get("run", {}))
@@ -92,10 +121,6 @@ class EngineJobs:
         t = time.perf_counter()
         self.pipe.prepare(self.program, symmetrize=self.symmetrize)
         self.spans["build_s"] = time.perf_counter() - t
-        self.roots = None
-        if traffic.get("roots"):
-            rng = np.random.default_rng(seed)
-            self.roots = rng.choice(data.covered(), size=int(traffic["roots"]), replace=False)
         self.lower_bytes = work.engine_job_bytes(
             data.src, data.dst, data.num_vertices, symmetrize=self.symmetrize,
             weighted=traffic.get("weighted", False),
@@ -115,35 +140,16 @@ class EngineJobs:
         self.pipe = None
         return maps
 
-    def check(self, records: list, maps: dict, seed: int) -> dict:
-        d, t = self.data, self.traffic
-        sample = _sample(records, int(t.get("check_jobs", len(records))), seed)
-        covered = d.covered()
-        src, dst = d.src, d.dst
+    def arcs(self) -> tuple[np.ndarray, np.ndarray]:
+        """The job's arcs: the graph's, and each reversed where the traffic
+        symmetrizes."""
+        d = self.data
         if self.symmetrize:
-            src, dst = np.concatenate([d.src, d.dst]), np.concatenate([d.dst, d.src])
-        compare = t["compare"]
-        worst = 0.0
-        if compare == "rank":
-            want = reference.pagerank(src, dst, d.num_vertices, **t["reference"])[covered]
-            for r in sample:
-                got = _to_global(r.out, maps, d.num_vertices)[covered]
-                err = np.abs(got - want) / want
-                worst = max(worst, float(np.max(np.where(np.isnan(err), np.inf, err))))
-            return {"rank_rel_err": worst}
-        if compare != "hops":
-            raise KeyError(f"unknown compare {compare!r}; known: hops, rank")
-        adj = reference.csr(src, dst, d.num_vertices)
-        mismatches = 0
-        for r in sample:
-            want = reference.hops(adj, r.root)[covered]
-            got = _to_global(r.out, maps, d.num_vertices)[covered]
-            got = np.where(got >= UNREACHED_HOPS, np.inf, got)
-            mismatches += int(np.count_nonzero(got != want))
-        return {"hops_mismatches": mismatches}
+            return np.concatenate([d.src, d.dst]), np.concatenate([d.dst, d.src])
+        return d.src, d.dst
 
 
-class PartitionJobs:
+class PartitionJobs(Jobs):
     """Each job partitions the host edge arrays on a fresh pipeline."""
 
     kind = "partition"
@@ -165,35 +171,31 @@ class PartitionJobs:
         self.graph = None
         return {}
 
-    def check(self, records: list, maps: dict, seed: int) -> dict:
-        d = self.data
-        (first,) = _sample(records, 1, seed)
-        regret = reference.ebv_regret(
-            d.src, d.dst, first.out, d.num_vertices, self.config["parts"],
-            **self.traffic.get("reference", {}),
-        )
-        differ = sum(int(np.count_nonzero(r.out != first.out)) for r in records)
-        return {"ebv_regret_max": float(regret.max()), "repeat_mismatches": differ}
-
 
 KINDS = {"engine": EngineJobs, "partition": PartitionJobs}
 
 
-def make_jobs(data: dict, config: dict, traffic: dict, seed: int):
+def make_jobs(data: dict, config: dict, traffic: dict, seed: int, compare) -> Jobs:
+    """The traffic's jobs over the generated `data`, checked by `compare`
+    (the module of `bench/compares/<compare>.py`)."""
     try:
         kind = KINDS[traffic["kind"]]
     except KeyError:
         raise KeyError(f"unknown traffic kind {traffic.get('kind')!r}; known: {sorted(KINDS)}") from None
-    return kind(GraphData(data), config, traffic, seed)
+    jobs = kind(GraphData(data), config, traffic, seed)
+    jobs.compare = compare
+    return jobs
 
 
-def _sample(records: list, k: int, seed: int) -> list:
+def sample(records: list, k: int, seed: int) -> list:
+    """`k` of the window's records (all, where it holds fewer), drawn from
+    the seed, in window order."""
     rng = np.random.default_rng(seed)
     idx = np.sort(rng.choice(len(records), size=min(k, len(records)), replace=False))
     return [records[i] for i in idx]
 
 
-def _to_global(values: np.ndarray, maps: dict, num_vertices: int) -> np.ndarray:
+def to_global(values: np.ndarray, maps: dict, num_vertices: int) -> np.ndarray:
     """Per-vertex values read at master replicas; NaN where no master holds
     one. A control's output is per-vertex already."""
     if values.ndim == 1:

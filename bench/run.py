@@ -3,12 +3,15 @@
     python3 -m bench.run --workload <config>.<traffic> --seed <n> --seconds <s> --trace <0|1>
 
 Everything is found by name under the checkout: the cell in
-`BENCHMARK.json`, its configuration in the file that entry names, its
-traffic mix in `bench/traffic/<traffic>.json`, each per-layer metric's
+`BENCHMARK.json`, its configuration in the file that entry names, the
+configuration's graph generator in `bench/generators/<name>.py`, its
+traffic mix in `bench/traffic/<traffic>.json`, the mix's answer comparison
+and its control in `bench/compares/<compare>.py`, each per-layer metric's
 reader in `bench/metrics/<metric>.py`, the device's peaks in
-`bench/peaks.json`. Adding a cell, a configuration, a mix or a metric adds
-files only. An end-to-end metric named `<quantity>.<suffix>` (`job_s.pr`)
-reports `<quantity>` in the cells it lists, under a bound of its own.
+`bench/peaks.json`. Adding a cell, a configuration, a generator, a mix, a
+comparison or a metric adds files only. An end-to-end metric named
+`<quantity>.<suffix>` (`job_s.pr`) reports `<quantity>` in the cells it
+lists, under a bound of its own.
 
 A run: check the device (a TPU, as many chips as the cell asks for, a kind
 listed in the peaks), make the graph on the device from the seed, set up
@@ -17,10 +20,10 @@ job: that is `setup_s`, from process start. Then jobs run back to back;
 the window closes at the first completion after `--seconds`. With
 `--trace 1` the profiler records the window and the per-layer metrics are
 read from it. After the window: the device's peak memory, then the
-program's state is freed and the window's answers are compared with the
-plain references in `bench.reference`. Each compared number and its limit
-go to standard error as the last lines, and into the result, which is the
-last line of standard output.
+program's state is freed and the mix's comparison checks the window's
+answers against the plain references in `bench.reference`. Each compared
+number and its limit go to standard error as the last lines, and into the
+result, which is the last line of standard output.
 """
 from __future__ import annotations
 
@@ -46,6 +49,7 @@ class Bench:
     def __init__(self, root=ROOT):
         self.root = pathlib.Path(root)
         self.spec = json.loads((self.root / "BENCHMARK.json").read_text())
+        self._modules = {}
 
     def _entry(self, key: str, name: str) -> dict:
         for entry in self.spec[key]:
@@ -70,13 +74,25 @@ class Bench:
         entries = self.spec["per_layer" if trace else "end_to_end"]
         return [m for m in entries if cell in m.get("workloads", [cell])]
 
+    def load(self, kind: str, name: str):
+        """The module `bench/<kind>/<name>.py` under `root`, loaded once per
+        `Bench`: a per-layer metric's reader (`metrics`), a graph generator
+        (`generators`), or an answer comparison with its control
+        (`compares`). An unknown name is a KeyError listing the known ones."""
+        path = self.root / "bench" / kind / f"{name}.py"
+        if path not in self._modules:
+            if not path.is_file():
+                known = sorted(p.stem for p in path.parent.glob("*.py") if not p.name.startswith("_"))
+                raise KeyError(f"unknown {kind[:-1]} {name!r}; known: {known}")
+            spec = importlib.util.spec_from_file_location(f"bench_{kind}_{name}", path)
+            module = importlib.util.module_from_spec(spec)
+            spec.loader.exec_module(module)
+            self._modules[path] = module
+        return self._modules[path]
+
     def reader(self, metric: str):
         """The `read(obs)` function of `bench/metrics/<metric>.py`."""
-        path = self.root / "bench" / "metrics" / f"{metric}.py"
-        spec = importlib.util.spec_from_file_location(f"bench_metric_{metric}", path)
-        module = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(module)
-        return module.read
+        return self.load("metrics", metric).read
 
 
 def check_device(chips: int, peaks: dict) -> dict:
@@ -152,7 +168,7 @@ def run_cell(bench: Bench, workload: str, seed: int, seconds: float, trace: bool
     warm-up: controls and planted faults use it."""
     import jax
 
-    from bench import generators, jobs as jobs_mod, trace as trace_mod
+    from bench import jobs as jobs_mod, trace as trace_mod
 
     cell = bench.cell(workload)
     config = bench.config(cell["config"])
@@ -160,11 +176,14 @@ def run_cell(bench: Bench, workload: str, seed: int, seconds: float, trace: bool
     if config["chips"] != cell["chips"]:
         raise SystemExit(f"bench: {workload} asks for {cell['chips']} chips, "
                          f"its configuration for {config['chips']}")
+    params = dict(config["generator"])
+    generator = bench.load("generators", params.pop("name"))
+    compare = bench.load("compares", traffic["compare"])
     compiles = CompileCounter()
     t = time.perf_counter()
-    data = generators.generate(config["generator"], seed)
+    data = generator.generate(seed, **params)
     generate_s = time.perf_counter() - t
-    jobs = jobs_mod.make_jobs(data, config, traffic, seed)
+    jobs = jobs_mod.make_jobs(data, config, traffic, seed, compare)
     jobs.spans["generate_s"] = generate_s
     if hook is not None:
         hook(jobs)

@@ -6,7 +6,8 @@ import numpy as np
 import pytest
 from scipy.sparse import csgraph
 
-from bench import generators, reference, work
+from bench import reference, run, work
+from bench_tiny import REPO
 from repro.api import GraphPipeline
 from repro.core.streaming_np import ebg_partition_np
 from repro.core.types import Graph
@@ -17,7 +18,8 @@ N = 1 << 10
 
 @pytest.fixture(scope="module")
 def g500():
-    g = generators.generate(dict(name="graph500", scale=10, edge_factor=16, a=0.57, b=0.19, c=0.19), 5)
+    graph500 = run.Bench(REPO).load("generators", "graph500")
+    g = graph500.generate(5, scale=10, edge_factor=16, a=0.57, b=0.19, c=0.19)
     return g, Graph(src=g["src"], dst=g["dst"], num_vertices=N)
 
 
